@@ -13,15 +13,17 @@ SLOW_MARKER := slow
 .PHONY: test test-slow test-all test-pallas bench-smoke bench scenarios \
 	baselines baselines-check trace traces advisor docs-check
 
+# The suite runs on the CPU: conftest.py initialises a backend in every
+# worker, and on a TPU host the workers would contend for the chip.
 test:            ## default tier-1 ($(SLOW_MARKER) excluded via pytest.ini)
-	$(PY) -m pytest -x -q
+	JAX_PLATFORMS=cpu $(PY) -m pytest -x -q
 
 test-slow:       ## full-fidelity runs only (the CI slow job)
-	$(PY) -m pytest -q -m "$(SLOW_MARKER)"
+	JAX_PLATFORMS=cpu $(PY) -m pytest -q -m "$(SLOW_MARKER)"
 
 test-all:        ## everything: tier-1 plus the slow suite, explicitly
-	$(PY) -m pytest -x -q
-	$(PY) -m pytest -q -m "$(SLOW_MARKER)"
+	JAX_PLATFORMS=cpu $(PY) -m pytest -x -q
+	JAX_PLATFORMS=cpu $(PY) -m pytest -q -m "$(SLOW_MARKER)"
 
 test-pallas:     ## pallas interpret-mode equivalence (the CI pallas job)
 	JAX_PLATFORMS=cpu $(PY) -m pytest -x -q tests/test_backend.py -k pallas

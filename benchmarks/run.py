@@ -48,6 +48,8 @@ def main() -> None:
     ap.add_argument("--artifacts", default=None, metavar="DIR",
                     help="write sections' CSV/JSON artifacts into DIR")
     args = ap.parse_args()
+    from repro.fabric.backend import use_compile_cache
+    use_compile_cache()
 
     sections = []
     artifact_writers = []

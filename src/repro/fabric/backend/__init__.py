@@ -45,6 +45,7 @@ default) and float64.
 from __future__ import annotations
 
 import enum
+import os
 from typing import Callable, Dict, Tuple, Union
 
 
@@ -211,6 +212,32 @@ def available_backends(name: str) -> Tuple[str, ...]:
     return tuple(b.value for (n, b) in _REGISTRY if n == name)
 
 
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at one fixed directory and
+    return it: the one ``JAX_COMPILATION_CACHE_DIR`` names, which JAX
+    reads itself, or else ``<repo>/.jax_cache``. A program calls this at
+    startup; importing the library sets no cache."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        *[os.pardir] * 4, ".jax_cache")
+    path = os.path.normpath(path)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def batched_eligible(scenario) -> bool:
+    """Whether the batched runner takes ``scenario`` in a counterfactual
+    sweep: static jobs, fairness inside :data:`JNP_SCENARIO_FAIRNESS`,
+    static routing."""
+    return (scenario.jobs is not None
+            and scenario.policies.fairness in JNP_SCENARIO_FAIRNESS
+            and getattr(scenario.policies, "routing", "ecmp_static")
+            == "ecmp_static")
+
+
 def counterfactual_sweep(scenarios, backend: Union[str, KernelType] = "jnp"
                          ) -> list:
     """Run an arbitrary scenario list for the what-if advisor
@@ -228,10 +255,7 @@ def counterfactual_sweep(scenarios, backend: Union[str, KernelType] = "jnp"
     eligible: list = []
     if kind in (KernelType.JNP, KernelType.PALLAS):
         eligible = [i for i, s in enumerate(scenarios)
-                    if s.jobs is not None
-                    and s.policies.fairness in JNP_SCENARIO_FAIRNESS
-                    and getattr(s.policies, "routing", "ecmp_static")
-                    == "ecmp_static"]
+                    if batched_eligible(s)]
     if eligible:
         from repro.fabric.backend.jnp_engine import run_scenarios
         try:

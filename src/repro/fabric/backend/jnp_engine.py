@@ -32,11 +32,18 @@ What runs where:
 Deliberate deviations from the reference (why ``scenario`` sits in the
 ``rtol`` equivalence tier, not ``exact``):
 
-  * float32 by default (float64 under ``jax.experimental.enable_x64``);
-  * the segment store is an unpruned ring buffer — semantically lossless
-    (stale segments overlap future windows by <= 0 and clamp to zero;
-    the reference's pruning threshold proves the same bound) until an
-    owner exceeds ``SEG_CAPACITY`` live segments;
+  * float32 by default (float64 under ``jax.enable_x64(True)``). Each
+    tenant's arrivals, skew and step are computed relative to its last
+    finish, and the absolute clocks that co-tenant windows and busy
+    segments are compared on are (whole seconds, fraction) pairs: a
+    plain float32 clock at 1e5 s resolves 8 ms, more than a rank's
+    compute spread or a window's overlap can afford to lose;
+  * the segment store is an unpruned ring buffer per owner. Stale
+    segments overlap future windows by <= 0 and clamp to zero (the
+    reference's pruning threshold proves the same bound). The scan flags
+    any overwrite of a segment the reference would still hold, and
+    :func:`run_scenarios` then reruns the group with one slot per
+    iteration, so no live segment is ever lost;
   * per-link byte totals are ``iters x bytes_per_call(None)`` — exact
     for ring/tree (static bytes; the reference's repeated adds differ
     only in accumulation rounding), the uncongested-winner approximation
@@ -79,7 +86,7 @@ from repro.fabric.engine import EngineResult, FabricEngine, JobResult
 from repro.fabric.stragglers import ComputeModel
 
 SUPPORTED_FAIRNESS = JNP_SCENARIO_FAIRNESS
-SEG_CAPACITY = 64                 # busy segments retained per owner
+SEG_CAPACITY = 64                 # first ring length tried per owner
 
 # -- pregenerated random streams (feedback-free, cached per seed) -----------
 
@@ -355,7 +362,22 @@ def _relu(x):
     return jnp.where(x > 0.0, x, 0.0)
 
 
-def _make_runner(static, kernels: KernelType):
+def _advance(hi, lo, dt):
+    """Advance the clock ``hi + lo`` by ``dt``: ``hi`` holds whole seconds
+    (exact in float32 up to 2**24 s), ``lo`` the fraction in ``[0, 1)``.
+    Both steps after the one add are exact."""
+    x = lo + dt
+    whole = jnp.floor(x)
+    return hi + whole, x - whole
+
+
+def _since(hi, lo, hi0, lo0):
+    """``(hi + lo) - (hi0 + lo0)``, exact to the rounding of the result:
+    clocks a window apart differ by a few seconds, not by their size."""
+    return (hi - hi0) + (lo - lo0)
+
+
+def _make_runner(static, kernels: KernelType, S: int):
     J = static["J"]
     L = static["L"]
     iters = static["iters"]
@@ -364,7 +386,11 @@ def _make_runner(static, kernels: KernelType):
     priorities = static["priorities"]
     used = static["used"]             # (J, L) static link-use mask
     multi = J > 1
-    S = SEG_CAPACITY
+    # owners whose segments some co-tenant reads (shares a used link)
+    shares = (used.astype(int) @ used.T.astype(int)) > 0
+    np.fill_diagonal(shares, False)
+    read = shares.any(axis=0)
+    others = ~np.eye(J, dtype=bool)
     # registry dispatch: allocators + overlap come from the requested
     # backend (jnp or pallas); the pacing bank stays on the jnp kernel
     # (it has no pallas registration — not one of the two hot paths).
@@ -421,35 +447,39 @@ def _make_runner(static, kernels: KernelType):
         for j in range(J):
             if sjobs[j]["pace"] is not None:
                 n, w, _ = sjobs[j]["pace"]
+                # window buffers, internal delay, release offsets
                 pace0.append((jnp.zeros((n, w)), jnp.zeros((n, w)),
                               jnp.zeros((n, w)), jnp.zeros(n),
                               jnp.zeros(n)))
             else:
-                pace0.append(jnp.zeros(()))    # scalar release clock
+                pace0.append(None)             # releases at its finish
+        # clocks are (whole seconds, fraction) pairs (module docstring);
+        # a busy segment is its start pair and its duration
         carry0 = (jnp.asarray(data["u0"]), tuple(pace0),
-                  jnp.zeros(J),                # prev_finish
-                  jnp.full((J, S), 0.0), jnp.full((J, S), -jnp.inf))
+                  (jnp.zeros(J), jnp.zeros(J)),        # prev finish
+                  (jnp.zeros((J, S)), jnp.zeros((J, S)),
+                   jnp.full((J, S), -jnp.inf)),        # segments
+                  jnp.zeros((), bool))         # a live segment was lost
 
         def step(carry, xs):
-            u, pace, prev_fin, seg_s, seg_e = carry
+            u, pace, (fin_hi, fin_lo), (seg_hi, seg_lo, seg_d), lost = carry
             t = xs["t"]
 
-            # 1. arrival windows
-            first, last, skew, arrivals = [], [], [], []
+            # 1. arrival windows, local to each tenant's last finish:
+            # float32 then resolves skew and step however far the
+            # absolute clocks have run
+            last_loc, skew, arrivals = [], [], []
             for j in range(J):
                 if sjobs[j]["pace"] is not None:
-                    rel_arr = pace[j][4]
-                    arr = rel_arr + xs[f"comp{j}"]
+                    arr = pace[j][4] + xs[f"comp{j}"]
                     arrivals.append(arr)
                     fj, lj = jnp.min(arr), jnp.max(arr)
                 else:
-                    rel = pace[j]
                     arrivals.append(None)
-                    fj = rel + xs[f"minc{j}"]
-                    lj = rel + xs[f"maxc{j}"]
-                first.append(fj)
-                last.append(lj)
+                    fj, lj = xs[f"minc{j}"], xs[f"maxc{j}"]
+                last_loc.append(lj)
                 skew.append((lj - fj) / data["floor"][j])
+            last_hi, last_lo = _advance(fin_hi, fin_lo, jnp.stack(last_loc))
 
             # 2. AR(1) background congestion
             u = rho * u + drift + iscale * (xs["z"] * sigma)
@@ -465,23 +495,26 @@ def _make_runner(static, kernels: KernelType):
             durs0 = [sched_total(j, effs[j], data) for j in range(J)]
 
             if multi:
-                s_v = jnp.stack(last)
-                e_v = s_v + jnp.stack(durs0)
+                d0_v = jnp.stack(durs0)
                 new_effs = []
                 for i in range(J):
                     own = sjobs[i]["own"]
-                    co = [k for k in range(J) if k != i]
-                    co_use = used[np.array(co)][:, own]     # (J-1, Lo)
+                    co = np.array([k for k in range(J) if k != i])
+                    co_use = used[co][:, own]               # (J-1, Lo)
                     if own.size == 0 or not co_use.any():
                         new_effs.append(effs[i])
                         continue
+                    # co-tenant windows and segments on job i's clock:
+                    # its window is [0, d_i)
                     d_i = durs0[i]
-                    same = _relu(jnp.minimum(e_v[i], e_v[jnp.array(co)])
-                                 - jnp.maximum(s_v[i],
-                                               s_v[jnp.array(co)]))
-                    seg = overlap_k(
-                        s_v[i], e_v[i], seg_s[jnp.array(co)],
-                        seg_e[jnp.array(co)])
+                    s_co = _since(last_hi[co], last_lo[co],
+                                  last_hi[i], last_lo[i])
+                    same = _relu(jnp.minimum(d_i, s_co + d0_v[co])
+                                 - jnp.maximum(0.0, s_co))
+                    seg_s = _since(seg_hi[co], seg_lo[co],
+                                   last_hi[i], last_lo[i])
+                    seg = overlap_k(jnp.zeros_like(d_i), d_i, seg_s,
+                                    seg_s + seg_d[co])
                     act = jnp.where(jnp.asarray(co_use.T),
                                     (same + seg)[None, :], 0.0)
                     d_safe = jnp.where(d_i > 0.0, d_i, 1.0)
@@ -495,14 +528,24 @@ def _make_runner(static, kernels: KernelType):
                         effs[i].at[own].set(effs[i][own] * share))
                 effs = new_effs
                 durs = [sched_total(j, effs[j], data) for j in range(J)]
+            else:
+                durs = durs0
+            durs_v = jnp.stack(durs)
+            fin_hi, fin_lo = _advance(last_hi, last_lo, durs_v)
+            if multi:
                 # record this round's busy segments (ring overwrite —
                 # stale entries clamp to zero overlap, no pruning needed)
                 slot = jnp.mod(t, S)
-                seg_s = seg_s.at[:, slot].set(jnp.stack(last))
-                seg_e = seg_e.at[:, slot].set(
-                    jnp.stack(last) + jnp.stack(durs))
-            else:
-                durs = durs0
+                # the reference keeps owner k's segment while it ends
+                # after some co-tenant's finish; overwriting one loses it
+                past = _since(seg_hi[:, slot, None], seg_lo[:, slot, None],
+                              fin_hi[None, :], fin_lo[None, :]) \
+                    + seg_d[:, slot, None]          # (owner, co-tenant)
+                lost = lost | jnp.any(read & jnp.any(others & (past > 0.0),
+                                                     axis=1))
+                seg_hi = seg_hi.at[:, slot].set(last_hi)
+                seg_lo = seg_lo.at[:, slot].set(last_lo)
+                seg_d = seg_d.at[:, slot].set(durs_v)
 
             # 4. queue-buildup kick, sequential per job
             for j in range(J):
@@ -512,21 +555,19 @@ def _make_runner(static, kernels: KernelType):
                 u = jnp.where((k_kick > 0.0) & (skew[j] > 0.0), u_k, u)
 
             # 5. BSP finish, step series, pacing, release updates
-            steps_t, new_pace, new_fin = [], [], []
+            steps_t, new_pace = [], []
             for j in range(J):
-                finish = last[j] + durs[j]
-                steps_t.append(jnp.where(t > 0, finish - prev_fin[j],
-                                         finish))
-                new_fin.append(finish)
+                step_j = last_loc[j] + durs[j]     # finish - prev finish
+                steps_t.append(step_j)
                 if sjobs[j]["pace"] is None:
-                    new_pace.append(finish)
+                    new_pace.append(None)
                     continue
                 n, w, enabled = sjobs[j]["pace"]
-                bw_, be_, bs_, delay, rel_arr = pace[j]
+                bw_, be_, bs_, delay, rel_off = pace[j]
                 col = jnp.mod(t, w)
-                wt = last[j] - arrivals[j]
+                wt = last_loc[j] - arrivals[j]
                 wt = jnp.where(wt > 0.0, wt, 0.0)
-                st = finish - rel_arr
+                st = step_j - rel_off                # finish - release
                 st = jnp.where(st > 0.0, st, 0.0)
                 bw_ = bw_.at[:, col].set(wt)
                 be_ = be_.at[:, col].set(wt + delay)
@@ -538,10 +579,10 @@ def _make_runner(static, kernels: KernelType):
                     enabled=enabled, warmup_iters=pp[0],
                     cv_threshold=pp[1], skew_threshold=pp[2],
                     gain=pp[3], decay=pp[4], max_delay_frac=pp[5])
-                new_pace.append((bw_, be_, bs_, delay, finish + delays))
+                new_pace.append((bw_, be_, bs_, delay, delays))
 
-            carry = (u, tuple(new_pace), jnp.stack(new_fin), seg_s,
-                     seg_e)
+            carry = (u, tuple(new_pace), (fin_hi, fin_lo),
+                     (seg_hi, seg_lo, seg_d), lost)
             return carry, jnp.stack(steps_t)
 
         xs = {"t": jnp.arange(iters), "z": jnp.asarray(data["z"])}
@@ -549,18 +590,30 @@ def _make_runner(static, kernels: KernelType):
             for k in (f"comp{j}", f"minc{j}", f"maxc{j}"):
                 if k in data:
                     xs[k] = jnp.asarray(data[k])
-        _, steps = lax.scan(step, carry0, xs)
-        return steps                   # (iters, J)
+        carry, steps = lax.scan(step, carry0, xs)
+        return steps, carry[-1]        # (iters, J), ring overflowed
 
     return jax.jit(jax.vmap(single))
 
 
-def _get_runner(sig, static, kernels: KernelType):
-    key = (sig, kernels, bool(jax.config.jax_enable_x64))
+def _get_runner(sig, static, kernels: KernelType, S: int):
+    key = (sig, kernels, S, bool(jax.config.jax_enable_x64))
     fn = _RUNNERS.get(key)
     if fn is None:
-        fn = _RUNNERS[key] = _make_runner(static, kernels)
+        fn = _RUNNERS[key] = _make_runner(static, kernels, S)
     return fn
+
+
+def _run_group(static, sig, data, kernels: KernelType) -> np.ndarray:
+    """Run one structural group on a ``SEG_CAPACITY`` ring; if any
+    variant overwrote a live segment, run it again with a ring as long
+    as the run, which never overwrites one."""
+    iters = static["iters"]
+    S = min(SEG_CAPACITY, iters)
+    steps, lost = _get_runner(sig, static, kernels, S)(data)
+    if S < iters and np.asarray(lost).any():
+        steps, _ = _get_runner(sig, static, kernels, iters)(data)
+    return np.asarray(steps)
 
 
 # -- result assembly --------------------------------------------------------
@@ -609,8 +662,7 @@ def run_scenarios(items: Sequence[Tuple[object, Optional[object]]],
         static = preps[idxs[0]].static
         data = {k: np.stack([preps[i].data[k] for i in idxs])
                 for k in preps[idxs[0]].data}
-        runner = _get_runner(sig, static, kernels)
-        out = np.asarray(runner(data))
+        out = _run_group(static, sig, data, kernels)
         for b, i in enumerate(idxs):
             results[i] = _wrap(preps[i], out[b])
     return results

@@ -16,8 +16,9 @@ arXiv:2605.21187's 100k+-rank scenarios):
 
 Bit-exactness strategy (the ``exact`` equivalence tier): the reference
 allocators are a stable ascending sort followed by a sequential fill.
-Instead of sorting, the kernel computes each flow's *stable rank* with an
-O(n²) comparison matrix — ``rank[j] = #{k : key[k] < key[j] or
+Instead of sorting, the kernel computes each flow's *stable rank* with
+O(n²) comparisons, one static column at a time — ``rank[j] = #{k :
+key[k] < key[j] or
 (key[k] == key[j] and k < j)}`` — which reproduces Python ``sorted``'s
 tie-breaking exactly, then runs the fill as a ``fori_loop`` over rank
 positions, selecting each position's demand/weight by masked sum (adding
@@ -34,7 +35,10 @@ compile via ``pl.pallas_call`` with row blocks aligned to the sweep's
 variant×links grid (:func:`waterfill_specs`); elsewhere they run in
 interpret mode, so CI exercises the identical kernel code on CPU
 (``ops.backend(pallas_only=True)`` resolves ``auto`` to ``interpret``,
-never ``xla`` — these kernels have no XLA twin).
+never ``xla`` — these kernels have no XLA twin). On a TPU they never
+interpret unless the caller passes ``interpret=True``. Every loop over a
+lane column is a static Python loop: the TPU lowering has no
+dynamic lane slice.
 
 Pre-launch validation: the PR-6 NaN/negative-demand rejection contract
 holds on every backend — concrete (non-tracer) demands/capacity are
@@ -55,7 +59,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.fabric.backend import KernelType, register_kernel
+from repro.fabric.backend import BackendError, KernelType, register_kernel
 from repro.fabric.backend.jnp_kernels import check_demands_launch
 
 # Row-block sizing: sublane-aligned (float32 min tile is (8, 128)) and
@@ -70,12 +74,22 @@ def interpret_mode() -> bool:
 
     One resolution path with :mod:`repro.kernels.ops`: ``auto`` picks the
     real Pallas lowering on TPU and interpret mode elsewhere
-    (``pallas_only=True`` — there is no XLA twin to fall back to). A
-    forced ``xla`` likewise lands on interpret: it is the only way to
-    execute this kernel code off-TPU.
+    (``pallas_only=True`` — there is no XLA twin to fall back to). Off
+    the TPU a forced ``xla`` or ``interpret`` also lands on interpret: it
+    is the only way to execute this kernel code there. On a TPU either
+    one raises :class:`BackendError` rather than hide the device behind
+    the interpreter.
     """
     from repro.kernels import ops
-    return ops.backend(pallas_only=True) != "pallas"
+    mode = ops.backend(pallas_only=True)
+    if mode == "pallas":
+        return False
+    if jax.default_backend() == "tpu":
+        raise BackendError(
+            f"kernel backend {mode!r} on a TPU: the fabric Pallas kernels "
+            f"have no XLA twin and do not interpret on the device; use "
+            f"'auto' or 'pallas', or pass interpret=True")
+    return True
 
 
 def waterfill_specs(rows: int, n: int,
@@ -105,34 +119,34 @@ def waterfill_specs(rows: int, n: int,
 
 def _stable_rank(key: jnp.ndarray, n: int) -> jnp.ndarray:
     """Stable ascending rank of each ``key`` along the last axis —
-    exactly Python ``sorted``'s order (ties broken by original index)."""
-    ka = key[:, :, None]               # j axis
-    kb = key[:, None, :]               # k axis
-    jidx = lax.broadcasted_iota(jnp.int32, (n, n), 0)
-    kidx = lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    before = (kb < ka) | ((kb == ka) & (kidx < jidx))
-    return jnp.sum(before.astype(jnp.int32), axis=-1)
+    exactly Python ``sorted``'s order (ties broken by original index).
+    One static compare per column keeps every operand a 2-D tile."""
+    jidx = lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    rank = jnp.zeros(key.shape, jnp.int32)
+    for k in range(n):
+        kb = key[:, k:k + 1]
+        before = (kb < key) | ((kb == key) & (k < jidx))
+        rank = rank + before.astype(jnp.int32)
+    return rank
 
 
 def _fill_tile(d, w, remaining, n: int) -> jnp.ndarray:
     """The shared waterfill: one progressive fill of ``(br, n)`` demands
-    against per-row ``remaining`` capacity, weights ``w``. Operand-for-
-    operand the reference loop (see module docstring)."""
+    against per-row ``(br, 1)`` ``remaining`` capacity, weights ``w``.
+    Operand-for-operand the reference loop (see module docstring)."""
     rank = _stable_rank(d / w, n)
-
-    def wsum(i, s):                    # left-to-right, original order —
-        return s + w[:, i]             # the reference's running total
-
-    w_left = lax.fori_loop(0, n, wsum, jnp.zeros_like(remaining))
+    w_left = jnp.zeros_like(remaining)
+    for i in range(n):                 # left-to-right, original order —
+        w_left = w_left + w[:, i:i + 1]    # the reference's running total
 
     def fill(p, carry):
         remaining, w_left, alloc = carry
         sel = rank == p
-        dj = jnp.sum(jnp.where(sel, d, 0.0), axis=-1)
-        wj = jnp.sum(jnp.where(sel, w, 0.0), axis=-1)
+        dj = jnp.sum(jnp.where(sel, d, 0.0), axis=-1, keepdims=True)
+        wj = jnp.sum(jnp.where(sel, w, 0.0), axis=-1, keepdims=True)
         fair = jnp.where(w_left > 0.0, remaining * wj / w_left, remaining)
         give = jnp.where(dj < fair, dj, fair)
-        alloc = jnp.where(sel, give[:, None], alloc)
+        alloc = jnp.where(sel, give, alloc)
         return remaining - give, w_left - wj, alloc
 
     _, _, alloc = lax.fori_loop(0, n, fill,
@@ -141,7 +155,7 @@ def _fill_tile(d, w, remaining, n: int) -> jnp.ndarray:
 
 
 def _waterfill_kernel(d_ref, w_ref, cap_ref, o_ref, *, n: int):
-    o_ref[...] = _fill_tile(d_ref[...], w_ref[...], cap_ref[...][:, 0], n)
+    o_ref[...] = _fill_tile(d_ref[...], w_ref[...], cap_ref[...], n)
 
 
 def _strict_priority_kernel(d_ref, m_ref, cap_ref, o_ref, *, n: int,
@@ -153,20 +167,16 @@ def _strict_priority_kernel(d_ref, m_ref, cap_ref, o_ref, *, n: int,
     clamp."""
     d = d_ref[...]
     masks = m_ref[...]                 # (n_classes, n), 1.0/0.0, static
-    remaining = cap_ref[...][:, 0]
+    remaining = cap_ref[...]           # (br, 1)
     ones = jnp.ones_like(d)
     alloc = jnp.zeros_like(d)
     for c in range(n_classes):         # static class count: unrolled
-        mask = masks[c] != 0.0
-        sub = _fill_tile(jnp.where(mask[None, :], d, 0.0), ones,
-                         remaining, n)
-        sub = jnp.where(mask[None, :], sub, 0.0)
+        mask = masks[c:c + 1, :] != 0.0
+        sub = _fill_tile(jnp.where(mask, d, 0.0), ones, remaining, n)
+        sub = jnp.where(mask, sub, 0.0)
         alloc = alloc + sub
-
-        def rsub(i, r):
-            return r - sub[:, i]
-
-        remaining = lax.fori_loop(0, n, rsub, remaining)
+        for i in range(n):             # index order, as the reference
+            remaining = remaining - sub[:, i:i + 1]
         remaining = jnp.where(remaining < 0.0, 0.0, remaining)
     o_ref[...] = alloc
 
@@ -177,12 +187,10 @@ def _segment_overlap_kernel(si_ref, ei_ref, s_ref, e_ref, o_ref, *,
     ei = ei_ref[...]
     ov = jnp.minimum(ei, e_ref[...]) - jnp.maximum(si, s_ref[...])
     ov = jnp.where(ov > 0.0, ov, 0.0)
-
-    def acc(k, t):                     # reference encounter order
-        return t + ov[:, k]
-
-    o_ref[...] = lax.fori_loop(0, n_segs, acc,
-                               jnp.zeros_like(si[:, 0]))[:, None]
+    t = jnp.zeros_like(si)
+    for k in range(n_segs):            # reference encounter order
+        t = t + ov[:, k:k + 1]
+    o_ref[...] = t
 
 
 def _launch_waterfill(d2, w2, cap2, n: int,

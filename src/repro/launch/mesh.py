@@ -20,14 +20,21 @@ import jax
 from repro.configs.base import MeshConfig, MULTI_POD_MESH, SINGLE_POD_MESH
 
 
+def _auto(n: int) -> tuple:
+    """Auto axis types: the sharding rules here are constraints the
+    compiler propagates, not explicit per-array types."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(cfg: MeshConfig) -> jax.sharding.Mesh:
-    return jax.make_mesh(tuple(cfg.shape), tuple(cfg.axes))
+    return jax.make_mesh(tuple(cfg.shape), tuple(cfg.axes),
+                         axis_types=_auto(len(cfg.axes)))
 
 
 def make_local_mesh(model_parallel: Optional[int] = None
@@ -35,7 +42,8 @@ def make_local_mesh(model_parallel: Optional[int] = None
     """Smoke/test mesh over whatever devices exist (usually 1 CPU)."""
     n = len(jax.devices())
     mp = model_parallel or 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def mesh_config_for(mesh: jax.sharding.Mesh) -> MeshConfig:

@@ -67,42 +67,28 @@ def active_mesh() -> Optional[Mesh]:
     return _ctx.mesh
 
 
-def _abstract_mesh():
-    """Ambient AbstractMesh, or None when this jax doesn't expose one.
-
-    ``jax.sharding.get_abstract_mesh`` landed after 0.4.x; on older
-    runtimes there is no manual-region trace context to consult, so the
-    callers below correctly fall through to the bound concrete mesh.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
+def _manual_abstract_mesh():
+    """The ambient AbstractMesh when inside a shard_map manual region,
+    else None."""
+    amesh = jax.sharding.get_abstract_mesh()
+    if amesh.empty or not amesh._any_axis_manual:
         return None
-    try:
-        return get()
-    except Exception:  # noqa: BLE001 — API drift fallback
-        return None
+    return amesh
 
 
 def manual_axes() -> frozenset:
     """Mesh axes currently bound Manual by an enclosing shard_map."""
-    amesh = _abstract_mesh()
-    if amesh is None or amesh.empty:
-        return frozenset()
-    try:
-        return frozenset(a for a in amesh.axis_names
-                         if amesh._name_to_type[a] ==
-                         jax.sharding.AxisType.Manual)
-    except Exception:  # noqa: BLE001 — API drift fallback
-        return frozenset()
+    amesh = jax.sharding.get_abstract_mesh()
+    return frozenset(a for a in amesh.axis_names
+                     if amesh._name_to_type[a] ==
+                     jax.sharding.AxisType.Manual)
 
 
 def shard_map_mesh():
     """Mesh object to hand to a nested shard_map: the ambient abstract
     mesh when inside a manual region, else the bound concrete mesh."""
-    amesh = _abstract_mesh()
-    if amesh is not None and not amesh.empty and amesh._any_axis_manual:
-        return amesh
-    return _ctx.mesh
+    amesh = _manual_abstract_mesh()
+    return _ctx.mesh if amesh is None else amesh
 
 
 def fallbacks() -> List[Tuple[str, int, int]]:
@@ -166,11 +152,8 @@ def logical(x: jax.Array, *spec: Union[str, None, Tuple[str, ...]]):
     # Inside a shard_map manual region the trace context carries an
     # AbstractMesh with Manual axis types; constraints must be built
     # against it (rules must not mention the manual axes there).
-    amesh = _abstract_mesh()
-    if amesh is not None and not amesh.empty and amesh._any_axis_manual:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(amesh, p))
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(_ctx.mesh, p))
+        x, NamedSharding(shard_map_mesh(), p))
 
 
 def named_sharding(shape: Sequence[int], spec: LogicalSpec) -> NamedSharding:

@@ -26,6 +26,7 @@ backend-equivalence job also runs ``benchmarks.run --only backend`` for
 the 50x target, and the pallas-interpret job runs the ``-k pallas``
 subset under ``JAX_PLATFORMS=cpu``).
 """
+import dataclasses
 import random
 
 import numpy as np
@@ -131,7 +132,7 @@ def test_allocator_kernels_bit_exact_under_x64(name):
     ref = get_kernel(name, KernelType.REFERENCE)
     fast = get_kernel(name, "jnp")
     rng = random.Random(5)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for trial in range(60):
             n = rng.randint(1, 8)
             d = _rand_demands(rng, n)
@@ -158,7 +159,7 @@ def test_offered_share_kernel_bit_exact_under_x64():
     ref = get_kernel("offered_share", KernelType.REFERENCE)
     fast = get_kernel("offered_share", "jnp")
     rng = random.Random(6)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for trial in range(60):
             d_i = rng.uniform(0.05, 2.0)
             # own_bytes == 0.0 hits the RESIDUAL_SHARE floor on both paths
@@ -182,7 +183,7 @@ def test_maxmin_kernel_zero_padding_is_exact():
     property the jnp engine's fixed-width owner matrices rely on."""
     fast = get_kernel("maxmin_shares", "jnp")
     rng = random.Random(13)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for _ in range(30):
             n = rng.randint(1, 6)
             d = [rng.uniform(0.0, 2.0) for _ in range(n)]
@@ -201,7 +202,7 @@ def test_allocator_kernels_vmap_batch_matches_per_row(name):
     fast = get_kernel(name, "jnp")
     rng = np.random.default_rng(3)
     D = rng.uniform(0.0, 2.0, size=(16, 5))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         if name == "wfq_shares":
             W = rng.uniform(0.1, 2.0, size=(16, 5))
             batched = np.asarray(jax.vmap(
@@ -226,7 +227,7 @@ def test_segment_overlap_kernel_within_ulp_tier():
     assert tier == "ulp"
     fast = get_kernel("segment_overlap", "jnp")
     rng = random.Random(7)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for trial in range(60):
             k = rng.randint(1, 12)
             starts = np.array([rng.uniform(0.0, 10.0) for _ in range(k)])
@@ -265,7 +266,7 @@ def test_pacing_decide_kernel_within_ulp_tier():
     n = 8
     bank = PacingBank(cfg, n)
     rng = random.Random(9)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for _ in range(5):
             for _ in range(cfg.window):   # full wraps keep the cursor at 0
                 bank.observe(
@@ -288,13 +289,19 @@ def test_pacing_decide_kernel_within_ulp_tier():
 # ---------------------------------------------------------------------------
 
 
-def _scenario(fairness="maxmin", *, backend=None, paced=False, name="bk"):
+def _scenario(fairness="maxmin", *, backend=None, paced=False, name="bk",
+              tenants=2, placement="compact", iters=40, warmup=5):
     from repro.fabric.congestion import CongestionConfig
     from repro.fabric.engine import JobSpec
     from repro.fabric.scenario import Policies, Scenario, TopologySpec
 
     pol = {} if backend is None else {"backend": backend}
-    if fairness == "strict_priority":
+    if tenants != 2:
+        # co-tenants of three sizes, weights and priority classes
+        jobs = [JobSpec(f"t{j}", 8, placement=placement,
+                        grad_bytes=2e9 * (1 + j % 3), weight=1.0 + j,
+                        priority=j % 3) for j in range(tenants)]
+    elif fairness == "strict_priority":
         jobs = [JobSpec("a", 16, priority=5), JobSpec("b", 16, priority=0)]
     else:
         jobs = [JobSpec("a", 16), JobSpec("b", 16)]
@@ -311,28 +318,77 @@ def _scenario(fairness="maxmin", *, backend=None, paced=False, name="bk"):
         jobs=jobs,
         congestion=CongestionConfig(k_kick=0.25),
         policies=Policies(fairness=fairness, **pol),
-        iters=40, warmup=5)
+        iters=iters, warmup=warmup)
 
 
 def _series_close(ref_res, jnp_res, rtol):
-    for jname in ("a", "b"):
-        a = np.array(ref_res.series(jname))
-        b = np.array(jnp_res.series(jname))
+    for job in ref_res.scenario.jobs:
+        a = np.array(ref_res.series(job.name))
+        b = np.array(jnp_res.series(job.name))
         assert a.shape == b.shape and len(a) > 0
         assert np.allclose(a, b, rtol=rtol, atol=0.0), \
-            (jname, float(np.max(np.abs(a - b) / np.abs(a))))
+            (job.name, float(np.max(np.abs(a - b) / np.abs(a))))
+
+
+# Four tenants sharing every leaf up-link (striped/scattered) make the
+# step dynamics sensitive to rounding. Under wfq the reference run
+# against itself with u_mean moved by one ulp leaves the rtol tier at
+# iteration 26 (scattered) or 62 (striped), and the runner's own rounding
+# does the same from iteration 24. The per-iteration tier is asserted
+# over that horizon; the two tests after this one cover long runs.
+_TIER_CASES = [pytest.param(f, 2, "compact", 40, id=f)
+               for f in JNP_SCENARIO_FAIRNESS] + [
+    pytest.param(f, 4, pl, 20, id=f"{f}-4-{pl}")
+    for pl in ("striped", "scattered") for f in JNP_SCENARIO_FAIRNESS]
 
 
 @needs_jax
-@pytest.mark.parametrize("fairness", list(JNP_SCENARIO_FAIRNESS))
-def test_scenario_kernel_rtol_tier_under_x64(fairness):
+@pytest.mark.parametrize("fairness,tenants,placement,iters", _TIER_CASES)
+def test_scenario_kernel_rtol_tier_under_x64(fairness, tenants, placement,
+                                             iters):
     tier, tol = EQUIVALENCE_TIERS["scenario"]
     assert tier == "rtol"
-    scn = _scenario(fairness)
+    scn = _scenario(fairness, tenants=tenants, placement=placement,
+                    iters=iters, warmup=4 if tenants != 2 else 5)
     ref = scn.run()                       # reference backend (default)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fast = scn.run(backend="jnp")
     _series_close(ref, fast, tol)
+
+
+@needs_jax
+def test_cotenant_means_hold_past_the_rounding_horizon():
+    """Past the horizon the four-tenant series are different but equally
+    valid trajectories; what must still agree is each tenant's mean step
+    (busy segments lost from a too-short ring moved one by 6%)."""
+    scn = _scenario("wfq", tenants=4, placement="striped", iters=200,
+                    warmup=20)
+    ref = scn.run()
+    with jax.enable_x64(True):
+        fast = scn.run(backend="jnp")
+    for job in scn.jobs:
+        a = np.mean(ref.series(job.name))
+        b = np.mean(fast.series(job.name))
+        assert abs(b / a - 1.0) < 1e-2, (job.name, a, b)
+
+
+@needs_jax
+def test_segment_ring_grows_until_no_live_segment_is_lost():
+    """A fast tenant next to one three times slower reads the slow one's
+    busy segments from far more than ``SEG_CAPACITY`` iterations back;
+    the runner must lengthen its ring rather than overwrite them."""
+    from repro.fabric.backend import jnp_engine
+    from repro.fabric.engine import JobSpec
+
+    scn = _scenario("maxmin", iters=200)
+    scn = dataclasses.replace(scn, jobs=[
+        JobSpec("a", 16, placement="striped", grad_bytes=1e9),
+        JobSpec("b", 16, placement="striped", grad_bytes=8e9)])
+    assert scn.iters > 2 * jnp_engine.SEG_CAPACITY
+    ref = scn.run()
+    with jax.enable_x64(True):
+        fast = scn.run(backend="jnp")
+    _series_close(ref, fast, EQUIVALENCE_TIERS["scenario"][1])
 
 
 @needs_jax
@@ -354,7 +410,7 @@ def test_scenario_kernel_float32_production_tolerance():
 def test_paced_scenario_equivalence_under_x64():
     scn = _scenario("maxmin", paced=True)
     ref = scn.run()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fast = scn.run(backend="jnp")
     _series_close(ref, fast, EQUIVALENCE_TIERS["scenario"][1])
 
@@ -482,6 +538,46 @@ def test_pallas_only_auto_resolution_matrix():
 
 
 @needs_jax
+def test_fabric_kernels_never_interpret_on_a_tpu(monkeypatch):
+    """On a TPU the fabric kernels compile or refuse: ``auto``/``pallas``
+    lower for the device, and a forced ``xla`` or ``interpret`` raises
+    instead of running the interpreter."""
+    from repro.fabric.backend.pallas_kernels import interpret_mode
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "_BACKEND", None)
+    for forced in ("auto", "pallas"):
+        ops.set_backend(forced)
+        assert interpret_mode() is False, forced
+    for forced in ("xla", "interpret"):
+        ops.set_backend(forced)
+        with pytest.raises(BackendError, match="do not interpret"):
+            interpret_mode()
+
+
+@needs_jax
+def test_compile_cache_is_placed_from_outside_or_fixed(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set; without
+    it the cache sits at ``<repo>/.jax_cache``, the same on every call."""
+    import os
+    from repro.fabric.backend import use_compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    saved = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/set/outside")
+        assert use_compile_cache() == "/set/outside"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = use_compile_cache()
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert use_compile_cache() == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
+
+
+@needs_jax
 def test_pallas_waterfill_specs_block_geometry():
     """The TPU compile path's shape contract, unit-tested without TPU
     hardware: row blocks are sublane-aligned (multiples of 8), capped,
@@ -520,7 +616,7 @@ def test_pallas_allocators_bit_exact_under_x64(name):
     ref = get_kernel(name, KernelType.REFERENCE)
     fast = get_kernel(name, "pallas")
     rng = random.Random(11)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for trial in range(40):
             n = rng.randint(1, 8)
             d = _rand_demands(rng, n)
@@ -550,7 +646,7 @@ def test_pallas_allocator_edge_cases():
     mm = get_kernel("maxmin_shares", "pallas")
     wfq = get_kernel("wfq_shares", "pallas")
     sp = get_kernel("strict_priority_shares", "pallas")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         # zero-demand rows allocate exactly zero and nothing else
         z = np.zeros((3, 4))
         assert np.asarray(mm(z, 1.0)).tolist() == z.tolist()
@@ -603,7 +699,7 @@ def test_pallas_segment_overlap_within_ulp_tier():
     assert tier == "ulp"
     fast = get_kernel("segment_overlap", "pallas")
     rng = random.Random(17)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for trial in range(40):
             k = rng.randint(1, 12)
             starts = np.array([rng.uniform(0.0, 10.0) for _ in range(k)])
@@ -639,7 +735,7 @@ def test_scenario_pallas_rtol_tier_under_x64(fairness):
     assert tier == "rtol"
     scn = _scenario(fairness)
     ref = scn.run()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fast = scn.run(backend="pallas")
     _series_close(ref, fast, tol)
 
@@ -654,7 +750,7 @@ def test_grid_pallas_backend_matches_jnp_bits():
     grid = ScenarioGrid(_scenario("wfq"), {
         "congestion.u_mean": [0.2, 0.4],
     })
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         via_jnp = grid.run(backend="jnp")
         via_pallas = grid.run(backend="pallas")
     for (_, rj), (_, rp) in zip(via_jnp, via_pallas):
@@ -716,7 +812,7 @@ def test_grid_pallas_256_variant_congestion_sweep():
         "congestion.u_mean": [0.05 + 0.025 * i for i in range(16)],
         "congestion.k_burst": [0.25 * (i + 1) for i in range(16)],
     })
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         results = grid.run(backend="pallas")
     variants = grid.scenarios()
     assert len(results) == 256

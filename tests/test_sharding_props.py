@@ -20,7 +20,8 @@ from repro.optim import quantize_roundtrip
 
 @pytest.fixture()
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_resolve_spec_basic(mesh):
@@ -33,7 +34,8 @@ def test_resolve_spec_fallback_records(mesh):
     with shd.axis_rules(mesh):
         shd.resolve_spec((7,), ("heads",))   # 7 % 1 == 0 on 1-dev mesh: ok
         # simulate a 16-way model axis via a fake rule on data axis of size 1
-    big = jax.make_mesh((1, 1), ("data", "model"))
+    big = jax.make_mesh((1, 1), ("data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with shd.axis_rules(big):
         spec = shd.resolve_spec((8,), ("ff",))
         assert spec == jax.sharding.PartitionSpec("model")
