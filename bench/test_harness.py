@@ -1,0 +1,258 @@
+"""Tests of the benchmark harness, at test scale on the CPU."""
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+import time
+from types import SimpleNamespace
+
+import numpy as np
+import pytest
+
+from bench import cells, harness, reference, trace as tr
+
+ROOT = cells.ROOT
+
+
+def shrink_calibrate(scn, traffic):
+    scn = json.loads(json.dumps(scn))
+    scn["iters"], scn["warmup"] = 30, 5
+    return scn, dict(traffic, axes={"congestion.u_mean": [0.05, 0.1],
+                                    "congestion.u_sigma": [0.04, 0.16]})
+
+
+def shrink_seeds(scn, traffic):
+    scn = json.loads(json.dumps(scn))
+    scn["iters"], scn["warmup"] = 30, 5
+    return scn, dict(traffic, seed_axis=dict(traffic["seed_axis"], count=4))
+
+
+SHRINK = {"table1_n64.calibrate_grid": shrink_calibrate,
+          "table1_n64.seed_grid": shrink_seeds}
+
+
+def cotenant(fairness):
+    """Four 8-rank striped co-tenants on a 64-node fat tree: the plain
+    reference's contended path at test scale."""
+    from repro.fabric.congestion import CongestionConfig
+    from repro.fabric.engine import JobSpec
+    from repro.fabric.scenario import Policies, Scenario, TopologySpec
+    return Scenario(
+        name="cotenant", topology=TopologySpec(n_nodes=64,
+                                               nodes_per_leaf=8),
+        jobs=[JobSpec(f"t{j}", 8, placement="striped",
+                      grad_bytes=2e9 * (1 + j % 3), weight=1.0 + j,
+                      priority=j % 3) for j in range(4)],
+        policies=Policies(fairness=fairness),
+        congestion=CongestionConfig(k_kick=0.25), iters=60, warmup=0,
+        base_seed=4242).to_dict()
+
+
+def run_small(name, traced=False, seed=2 ** 31 + 12345, trace_dir=None):
+    import jax
+    return harness.run(name, seed, 0.3, traced, jax.devices()[0], 0.0,
+                       shrink=SHRINK[name], trace_dir=trace_dir)
+
+
+# -- trace reduction --------------------------------------------------------
+
+
+def test_trace_reduction_on_a_synthetic_trace():
+    ops = [("fusion.1", 1.0, 2.0), ("custom-call.2", 1.5, 2.5),
+           ("fusion.1", 4.0, 5.0)]
+    intervals = [(s, e) for _, s, e in ops]
+    window = (0.5, 6.0)
+    assert tr.union(intervals) == [(1.0, 2.5), (4.0, 5.0)]
+    assert tr.busy(intervals, window) == pytest.approx(2.5)
+    assert tr.busy(intervals, (2.0, 4.5)) == pytest.approx(1.0)
+    idle = tr.gaps(intervals, window)
+    assert idle == [(0.5, 1.0), (2.5, 4.0), (5.0, 6.0)]
+    assert sum(e - s for s, e in idle) == pytest.approx(5.5 - 2.5)
+    spans = [("sweep_call", 0.0, 3.0), ("wrap", 2.0, 2.8),
+             ("runner", 3.5, 5.5)]
+    assert tr.innermost(spans) == [(0.0, 2.0, "sweep_call"),
+                                   (2.0, 2.8, "wrap"),
+                                   (2.8, 3.0, "sweep_call"),
+                                   (3.5, 5.5, "runner")]
+    got = tr.attribute(idle, spans)
+    assert got == pytest.approx({"sweep_call": 0.7, "wrap": 0.3,
+                                 "runner": 1.0, "host_other": 1.0})
+    assert tr.op_seconds(ops) == pytest.approx({"fusion.1": 2.0,
+                                                "custom-call.2": 1.0})
+    assert tr.op_seconds(ops, (1.8, 4.5))["fusion.1"] == pytest.approx(0.7)
+    assert tr.top({"a": 1.0, "b": 3.0, "c": 2.0}, 2) == [["b", 3.0],
+                                                         ["c", 2.0]]
+    t = tr.Trace(ops, [], [("sweep", 0.5, 3.0), ("sweep", 3.0, 6.0)])
+    assert t.window() == (0.5, 6.0)
+
+
+def _plane(name, **lines):
+    ev = lambda n, s, d: SimpleNamespace(name=n, start_ns=s, duration_ns=d)
+    return SimpleNamespace(name=name, lines=[
+        SimpleNamespace(name=k, events=[ev(*e) for e in v])
+        for k, v in lines.items()])
+
+
+def test_trace_reads_device_lines_by_name():
+    host = _plane("/host:CPU", python=[("bench.sweep", 0, 100),
+                                       ("other", 10, 5)])
+    tpu = _plane("/device:TPU:0", **{
+        "XLA Ops": [("fusion", 20, 10)],
+        "XLA Modules": [("jit_single(1)", 15, 30)],
+        "Steps": [("0", 0, 100), ("1", 0, 100)]})
+    t = tr.from_planes([host, tpu, _plane("/device:TPU:1")])
+    ns = lambda evs: [(n, round(s * 1e9), round(e * 1e9)) for n, s, e in evs]
+    assert ns(t.ops) == [("fusion", 20, 30)]
+    assert ns(t.modules) == [("jit_single(1)", 15, 45)]
+    assert ns(t.spans) == [("sweep", 0, 100)]
+    assert tr.from_planes([host]).ops == []     # no TPU: nothing to read
+    # no stand-in for a missing ops line, however busy another line is
+    with pytest.raises(ValueError, match="XLA Ops"):
+        tr.from_planes([host, _plane("/device:TPU:0", Steps=[("0", 0, 9)])])
+
+
+# -- cells ------------------------------------------------------------------
+
+
+def _expected(name):
+    """What the cell files should build: Table 1's 64-node baseline as
+    the repository fits it, and the grid ``calibrate()`` sweeps around a
+    fit by default (or that configuration as it stands)."""
+    from repro.fabric import SimConfig, scenario_from
+    base = scenario_from(SimConfig.paper(64, coordination=False, seed=0))
+    if name == "table1_n64.calibrate_grid":
+        u = base.congestion.u_mean
+        return base, {"congestion.u_mean": sorted({u * 0.5, u, u * 1.5}),
+                      "congestion.u_sigma": [0.04, 0.08, 0.16]}
+    return base, {}
+
+
+@pytest.mark.parametrize("name", sorted(SHRINK))
+def test_cell_files_build_the_repository_grids(name):
+    from repro.fabric.scenario import Scenario, ScenarioGrid
+    spec = cells.cell(name)
+    base, axes = _expected(name)
+    got = Scenario.from_dict(spec["config_data"]["scenario"])
+    assert dataclasses.replace(got, name=base.name) == base
+    traffic = spec["traffic_data"]
+    assert list(traffic["axes"]) == list(axes)
+    for k, v in axes.items():
+        assert traffic["axes"][k] == pytest.approx(v)
+    bs = cells.base_seed(1, 0, traffic["seeds"])
+    sweep = cells.sweep_axes(traffic, bs)
+    mine = cells.variants(spec["config_data"]["scenario"], sweep, bs)
+    want = ScenarioGrid(base.replace(base_seed=bs), sweep)
+    assert len(mine) == len(want) == {"table1_n64.calibrate_grid": 9,
+                                      "table1_n64.seed_grid": 64}[name]
+    for (p, d), (q, s) in zip(mine, want):
+        assert p == q
+        assert dataclasses.replace(Scenario.from_dict(d), name=s.name) == s
+
+
+def test_benchmark_json_names_every_file():
+    bench = cells.benchmark()
+    for m in bench["per_layer"]:
+        assert os.path.exists(os.path.join(ROOT, "bench", "metrics",
+                                           m["name"] + ".py"))
+    for w in bench["workloads"]:
+        cells.cell(w["name"])                      # loads all three files
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 5, 2 ** 33 - 1])
+def test_sweep_seeds_never_share_a_stream(seed):
+    traffic = cells.cell("table1_n64.seed_grid")["traffic_data"]
+    seeds = traffic["seeds"]
+    got = [cells.base_seed(seed, i, seeds) for i in range(-1, 3000)]
+    assert all(0 <= b < 2 ** 31 for b in got)
+    assert got == [cells.base_seed(seed, i, seeds) for i in range(-1, 3000)]
+    assert cells.base_seed(seed + 1, 0, seeds) != got[1]
+    # every seed a variant uses, and those a scenario derives from it
+    # (base + 2, base + 1 + 1009 j per tenant), belongs to one variant of
+    # one sweep
+    used = sorted(s for b in got
+                  for s in cells.sweep_axes(traffic, b)["base_seed"])
+    assert all(b - a >= traffic["seed_axis"]["stride"]
+               for a, b in zip(used, used[1:]))
+    assert 2 < traffic["seed_axis"]["stride"]
+    assert traffic["seed_axis"]["stride"] * traffic["seed_axis"]["count"] \
+        <= seeds["stride"]
+
+
+def test_run_exits_nonzero_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "bench/run.py", "--workload",
+                        "table1_n64.calibrate_grid", "--seed", "1",
+                        "--seconds", "1", "--trace", "0"],
+                       cwd=ROOT, env=env, capture_output=True, text=True,
+                       timeout=120)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "TPU" in p.stderr
+
+
+# -- the plain reference ----------------------------------------------------
+
+
+@pytest.mark.parametrize("fairness", ["maxmin", "wfq", "strict_priority",
+                                      "single"])
+def test_plain_reference_is_the_engine_bit_for_bit(fairness):
+    from repro.fabric.scenario import Scenario
+    if fairness == "single":                    # the cells' own job
+        name = "table1_n64.calibrate_grid"
+        scn, _ = SHRINK[name](cells.cell(name)["config_data"]["scenario"],
+                              {})
+        scn["iters"], scn["base_seed"] = 60, 4242
+    else:
+        scn = cotenant(fairness)
+    res = Scenario.from_dict(scn).run(backend="reference")
+    want = np.stack([res.series(j["name"]) for j in scn["jobs"]], axis=1)
+    got = reference.series(scn)
+    assert got.shape == want.shape
+    assert np.array_equal(got, want)
+
+
+def test_plain_reference_refuses_what_it_does_not_model():
+    scn = cells.cell("table1_n64.calibrate_grid")["config_data"]["scenario"]
+    scn = json.loads(json.dumps(scn))
+    scn["jobs"][0]["algo"] = "tree"
+    with pytest.raises(ValueError):
+        list(reference.steps(scn))
+
+
+# -- a whole run at test scale ----------------------------------------------
+
+
+@pytest.mark.parametrize("name", sorted(SHRINK))
+def test_cpu_rehearsal_of_a_run(name, tmp_path):
+    out = run_small(name)
+    assert out["correct"] is True
+    assert out["failed"] == 0 and out["attempted"] > 0
+    assert set(out["metrics"]) == {m["name"] for m in
+                                   cells.cell(name)["end_to_end"]}
+    assert all(m["value"] > 0 for m in out["metrics"].values())
+    assert list(out)[-1] == "checks"
+    assert out["window"]["compiles"] == 0
+    assert out["device"]["platform"] == "cpu"
+    traced = run_small(name, traced=True, trace_dir=str(tmp_path))
+    assert traced["correct"] is True
+    assert {"prep_us_per_variant", "wrap_us_per_variant"} \
+        <= set(traced["metrics"])
+    assert traced["device"]["window_s"] > 0
+    assert {"device_ops", "idle_gaps"} <= set(traced["breakdown"])
+
+
+def test_window_leaves_out_the_harness_bookkeeping(monkeypatch):
+    count = harness._count_failed
+
+    def slow(*args):
+        time.sleep(0.2)
+        return count(*args)
+
+    monkeypatch.setattr(harness, "_count_failed", slow)
+    t0 = time.perf_counter()
+    out = run_small("table1_n64.calibrate_grid")
+    w = out["window"]
+    assert w["seconds"] >= 0.3
+    assert w["seconds"] <= w["sweeps"] * w["sweep_s_max"] + 1e-9
+    assert time.perf_counter() - t0 > w["seconds"] + 0.2 * w["sweeps"]
