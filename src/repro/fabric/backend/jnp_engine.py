@@ -63,6 +63,13 @@ the kernel registry for the requested backend (``kernels=`` on
 ``backend="jnp"`` (:mod:`repro.fabric.backend.jnp_kernels`) and
 ``backend="pallas"`` (:mod:`repro.fabric.backend.pallas_kernels`, where
 the fused waterfill and overlap kernels run via ``pl.pallas_call``).
+
+Telemetry: each host step of a sweep is a ``fabric.*`` span of
+:mod:`repro.fabric.telemetry` (the sweep; per variant its preparation,
+engine build, encoding, random streams and result; per group the
+stacking, the runner call and its launch or build, wait, fetch and
+rerun) and each cache lookup a hit or miss counter. Telemetry is off
+unless enabled, and changes no result.
 """
 from __future__ import annotations
 
@@ -77,7 +84,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.fabric import _deprecation
+from repro.fabric import _deprecation, telemetry
 from repro.fabric.backend import (JNP_SCENARIO_FAIRNESS, BackendError,
                                   KernelType, get_kernel, register_kernel)
 from repro.fabric.backend import jnp_kernels as K
@@ -99,14 +106,18 @@ def _compute_stream(cfg, n: int, seed: int, iters: int) -> np.ndarray:
     bit-identical to the stream the reference engine consumes (the model
     holds no engine-fed state). Cached by (config, n, seed); the stream
     is prefix-stable, so a longer request regenerates once."""
-    key = (cfg, n, seed)
-    hit = _COMPUTE_CACHE.get(key)
-    if hit is None or hit.shape[0] < iters:
-        cm = ComputeModel(cfg, n, seed=seed)
-        hit = np.array([cm.sample() for _ in range(iters)],
-                       dtype=np.float64)
-        _COMPUTE_CACHE[key] = hit
-    return hit[:iters]
+    with telemetry.span("fabric.prep.compute_stream"):
+        key = (cfg, n, seed)
+        hit = _COMPUTE_CACHE.get(key)
+        if hit is None or hit.shape[0] < iters:
+            telemetry.count("fabric.compute_stream.miss")
+            cm = ComputeModel(cfg, n, seed=seed)
+            hit = np.array([cm.sample() for _ in range(iters)],
+                           dtype=np.float64)
+            _COMPUTE_CACHE[key] = hit
+        else:
+            telemetry.count("fabric.compute_stream.hit")
+        return hit[:iters]
 
 
 def _gauss_stream(seed: int, count: int) -> np.ndarray:
@@ -115,26 +126,30 @@ def _gauss_stream(seed: int, count: int) -> np.ndarray:
     including the sin/cos pair cache carried across ``advance()`` calls —
     bit-identical regardless of how the stream splits across iterations
     or how ``random.gauss`` evolves between Python versions."""
-    key = (seed,)
-    hit = _GAUSS_CACHE.get(key)
-    if hit is None or hit.shape[0] < count:
-        rnd = random.Random(seed).random
-        cos, sin, log, sqrt = math.cos, math.sin, math.log, math.sqrt
-        twopi = 2.0 * math.pi
-        out = np.empty(count, dtype=np.float64)
-        g_next = None
-        for i in range(count):
-            z = g_next
-            if z is None:
-                x2pi = rnd() * twopi
-                g2rad = sqrt(-2.0 * log(1.0 - rnd()))
-                z = cos(x2pi) * g2rad
-                g_next = sin(x2pi) * g2rad
-            else:
-                g_next = None
-            out[i] = z
-        _GAUSS_CACHE[key] = hit = out
-    return hit[:count]
+    with telemetry.span("fabric.prep.gauss_stream"):
+        key = (seed,)
+        hit = _GAUSS_CACHE.get(key)
+        if hit is None or hit.shape[0] < count:
+            telemetry.count("fabric.gauss_stream.miss")
+            rnd = random.Random(seed).random
+            cos, sin, log, sqrt = math.cos, math.sin, math.log, math.sqrt
+            twopi = 2.0 * math.pi
+            out = np.empty(count, dtype=np.float64)
+            g_next = None
+            for i in range(count):
+                z = g_next
+                if z is None:
+                    x2pi = rnd() * twopi
+                    g2rad = sqrt(-2.0 * log(1.0 - rnd()))
+                    z = cos(x2pi) * g2rad
+                    g_next = sin(x2pi) * g2rad
+                else:
+                    g_next = None
+                out[i] = z
+            _GAUSS_CACHE[key] = hit = out
+        else:
+            telemetry.count("fabric.gauss_stream.hit")
+        return hit[:count]
 
 
 # -- schedule encoding ------------------------------------------------------
@@ -239,6 +254,8 @@ def _build_jobs(scenario, topo):
     key = (scenario.topology, scenario.jobs, scenario.policies.fairness,
            scenario.policies.routing, scenario.base_seed)
     hit = _ENGINE_CACHE.get(key)
+    telemetry.count("fabric.engine_cache.miss" if hit is None
+                    else "fabric.engine_cache.hit")
     if hit is None:
         topo = scenario.topology.build()
         with _deprecation.scenario_scope():
@@ -252,25 +269,36 @@ def _build_jobs(scenario, topo):
 
 
 def _prep(scenario, topo=None, backend: str = "jnp") -> _Prep:
-    if scenario.jobs is None:
-        raise BackendError(
-            f"backend={backend!r} runs static-jobs scenarios only; "
-            f"unsupported feature: events= (lifecycle timeline); nearest "
-            f"supported backend: 'reference'")
+    with telemetry.span("fabric.prep"):
+        if scenario.jobs is None:
+            raise BackendError(
+                f"backend={backend!r} runs static-jobs scenarios only; "
+                f"unsupported feature: events= (lifecycle timeline); "
+                f"nearest supported backend: 'reference'")
+        fairness = scenario.policies.fairness
+        if fairness not in SUPPORTED_FAIRNESS:
+            raise BackendError(
+                f"backend={backend!r} supports fairness "
+                f"{SUPPORTED_FAIRNESS}; unsupported feature: "
+                f"fairness={fairness!r}; nearest supported backend: "
+                f"'reference'")
+        from repro.fabric.policies import ROUTING
+        if ROUTING.get(scenario.policies.routing).adaptive:
+            raise BackendError(
+                f"backend={backend!r} runs static-jobs scenarios only; "
+                f"unsupported feature: "
+                f"routing={scenario.policies.routing!r} (per-iteration "
+                f"byte re-split); nearest supported backend: 'reference'")
+        with telemetry.span("fabric.prep.engine"):
+            topo, jobs = _build_jobs(scenario, topo)
+        with telemetry.span("fabric.prep.encode"):
+            return _encode_variant(scenario, topo, jobs)
+
+
+def _encode_variant(scenario, topo, jobs) -> _Prep:
+    """One variant's group signature, static structure and per-variant
+    arrays: schedules encoded, random streams drawn (or found cached)."""
     fairness = scenario.policies.fairness
-    if fairness not in SUPPORTED_FAIRNESS:
-        raise BackendError(
-            f"backend={backend!r} supports fairness {SUPPORTED_FAIRNESS}; "
-            f"unsupported feature: fairness={fairness!r}; nearest "
-            f"supported backend: 'reference'")
-    from repro.fabric.policies import ROUTING
-    if ROUTING.get(scenario.policies.routing).adaptive:
-        raise BackendError(
-            f"backend={backend!r} runs static-jobs scenarios only; "
-            f"unsupported feature: routing={scenario.policies.routing!r} "
-            f"(per-iteration byte re-split); nearest supported backend: "
-            f"'reference'")
-    topo, jobs = _build_jobs(scenario, topo)
     J = len(jobs)
     iters = scenario.iters
     if topo.sparse_links:
@@ -600,20 +628,41 @@ def _get_runner(sig, static, kernels: KernelType, S: int):
     key = (sig, kernels, S, bool(jax.config.jax_enable_x64))
     fn = _RUNNERS.get(key)
     if fn is None:
+        telemetry.count("fabric.runner.build")
         fn = _RUNNERS[key] = _make_runner(static, kernels, S)
     return fn
+
+
+def _launch(sig, static, kernels: KernelType, S: int, data):
+    """Look the runner up and launch it on ``data``: the call moves the
+    stacked host arrays to the device and enqueues the program. The first
+    call of a runner just built also traces and compiles it (or loads it
+    from the persistent cache): that call is ``fabric.runner.build``."""
+    known = len(_RUNNERS)
+    fn = _get_runner(sig, static, kernels, S)
+    built = len(_RUNNERS) > known
+    with telemetry.span("fabric.runner.build" if built
+                        else "fabric.runner.launch"):
+        return fn(data)
 
 
 def _run_group(static, sig, data, kernels: KernelType) -> np.ndarray:
     """Run one structural group on a ``SEG_CAPACITY`` ring; if any
     variant overwrote a live segment, run it again with a ring as long
     as the run, which never overwrites one."""
-    iters = static["iters"]
-    S = min(SEG_CAPACITY, iters)
-    steps, lost = _get_runner(sig, static, kernels, S)(data)
-    if S < iters and np.asarray(lost).any():
-        steps, _ = _get_runner(sig, static, kernels, iters)(data)
-    return np.asarray(steps)
+    with telemetry.span("fabric.runner"):
+        iters = static["iters"]
+        S = min(SEG_CAPACITY, iters)
+        steps, lost = _launch(sig, static, kernels, S, data)
+        with telemetry.span("fabric.runner.wait"):
+            lost = np.asarray(lost)
+        if S < iters and lost.any():
+            telemetry.count("fabric.runner.rerun")
+            with telemetry.span("fabric.runner.rerun"):
+                steps, _ = _launch(sig, static, kernels, iters, data)
+                steps.block_until_ready()
+        with telemetry.span("fabric.runner.fetch"):
+            return np.asarray(steps)
 
 
 # -- result assembly --------------------------------------------------------
@@ -624,20 +673,21 @@ def _wrap(prep: _Prep, steps: np.ndarray):
     byte totals are ``iters x bytes_per_call(None)`` (see module
     docstring); traces are empty (no per-rank record matrices)."""
     from repro.fabric.scenario import Result
-    iters = prep.scenario.iters
-    job_results = []
-    fabric: Dict[str, float] = {}
-    for j, jr in enumerate(prep.jobs):
-        series = [float(x) for x in steps[prep.warmup:, j]]
-        link_bytes = {ln: iters * b for ln, b
-                      in jr.schedule.bytes_per_call(None).items()}
-        for ln, b in link_bytes.items():
-            fabric[ln] = fabric.get(ln, 0.0) + b
-        job_results.append(JobResult(jr.spec, jr.nodes, series,
-                                     link_bytes, [], algo=jr.algo))
-    raw = EngineResult(topo=prep.topo, jobs=job_results,
-                       link_bytes=fabric)
-    return Result(prep.scenario, raw, prep.topo)
+    with telemetry.span("fabric.wrap"):
+        iters = prep.scenario.iters
+        job_results = []
+        fabric: Dict[str, float] = {}
+        for j, jr in enumerate(prep.jobs):
+            series = [float(x) for x in steps[prep.warmup:, j]]
+            link_bytes = {ln: iters * b for ln, b
+                          in jr.schedule.bytes_per_call(None).items()}
+            for ln, b in link_bytes.items():
+                fabric[ln] = fabric.get(ln, 0.0) + b
+            job_results.append(JobResult(jr.spec, jr.nodes, series,
+                                         link_bytes, [], algo=jr.algo))
+        raw = EngineResult(topo=prep.topo, jobs=job_results,
+                           link_bytes=fabric)
+        return Result(prep.scenario, raw, prep.topo)
 
 
 def run_scenarios(items: Sequence[Tuple[object, Optional[object]]],
@@ -653,19 +703,22 @@ def run_scenarios(items: Sequence[Tuple[object, Optional[object]]],
     ``KernelType.PALLAS``).
     """
     kernels = KernelType.parse(kernels, default=KernelType.JNP)
-    preps = [_prep(s, t, backend=kernels.value) for s, t in items]
-    groups: Dict[tuple, List[int]] = {}
-    for i, p in enumerate(preps):
-        groups.setdefault(p.sig, []).append(i)
-    results: List[object] = [None] * len(preps)
-    for sig, idxs in groups.items():
-        static = preps[idxs[0]].static
-        data = {k: np.stack([preps[i].data[k] for i in idxs])
-                for k in preps[idxs[0]].data}
-        out = _run_group(static, sig, data, kernels)
-        for b, i in enumerate(idxs):
-            results[i] = _wrap(preps[i], out[b])
-    return results
+    with telemetry.span("fabric.sweep"):
+        preps = [_prep(s, t, backend=kernels.value) for s, t in items]
+        with telemetry.span("fabric.stack"):
+            groups: Dict[tuple, List[int]] = {}
+            for i, p in enumerate(preps):
+                groups.setdefault(p.sig, []).append(i)
+        results: List[object] = [None] * len(preps)
+        for sig, idxs in groups.items():
+            with telemetry.span("fabric.stack"):
+                static = preps[idxs[0]].static
+                data = {k: np.stack([preps[i].data[k] for i in idxs])
+                        for k in preps[idxs[0]].data}
+            out = _run_group(static, sig, data, kernels)
+            for b, i in enumerate(idxs):
+                results[i] = _wrap(preps[i], out[b])
+        return results
 
 
 @register_kernel("scenario", KernelType.JNP)
