@@ -11,9 +11,13 @@ The key structural fact that makes this possible: **every random stream
 the engine consumes is feedback-free.** Compute samples
 (:class:`~repro.fabric.stragglers.ComputeModel`) and the congestion
 AR(1) gaussians depend only on their seeds — never on simulation state —
-so both are pregenerated bit-identically in Python (and cached per seed,
-amortizing the host cost across grid variants that share streams) and
-the scan body is pure float arithmetic.
+so both are pregenerated on the host (and cached per seed, amortizing
+the host cost across grid variants that share streams) and the scan
+body is pure float arithmetic. Pregeneration replays the Python
+``random.Random`` stream in bulk through numpy's MT19937: the same
+uniform draws at the same positions, the same spike states, and float64
+values within 2 ulps of the Python ones (numpy's vector ``exp``/``log``
+are not libm's).
 
 What runs where:
 
@@ -68,7 +72,8 @@ Telemetry: each host step of a sweep is a ``fabric.*`` span of
 :mod:`repro.fabric.telemetry` (the sweep; per variant its preparation,
 engine build, encoding, random streams and result; per group the
 stacking, the runner call and its launch or build, wait, fetch and
-rerun) and each cache lookup a hit or miss counter. Telemetry is off
+rerun), each cache lookup a hit or miss counter, and each compute-stream
+miss the spike-chain steps it resolved one at a time. Telemetry is off
 unless enabled, and changes no result.
 """
 from __future__ import annotations
@@ -100,20 +105,140 @@ SEG_CAPACITY = 64                 # first ring length tried per owner
 _COMPUTE_CACHE: Dict[tuple, np.ndarray] = {}
 _GAUSS_CACHE: Dict[tuple, np.ndarray] = {}
 
+# Both streams are replayed through one numpy MT19937, handed the Python
+# generator's state on each miss. Its legacy ``random_sample`` is the
+# same 53-bit formula as ``random.Random.random``, so it returns the same
+# doubles in the same order.
+_MT = np.random.RandomState(0)
+# draws past a spike-free stream's count, for the heavy-tail draw that
+# each spike entry adds; the block grows from the same state past it
+HEAVY_MARGIN = 256
+
+
+def _take_over(rng: random.Random) -> np.random.RandomState:
+    words = rng.getstate()[1]      # the 624 state words, then the position
+    # a tuple of ints: numpy copies the key word by word, and from a
+    # tuple that is some twenty times faster than from an array
+    _MT.set_state(("MT19937", words[:624], words[624]))
+    return _MT
+
+
+def _box_muller(x: np.ndarray, y: np.ndarray) -> np.ndarray:
+    """The inlined Box-Muller pairs of ``ComputeModel.sample`` and
+    ``CongestionModel.advance`` over vectors of their uniforms ``x``,
+    ``y``, in the same operation order: cos then sin, interleaved."""
+    x2pi = x * (2.0 * math.pi)
+    g2rad = np.sqrt(-2.0 * np.log(1.0 - y))
+    z = np.empty(2 * x.shape[0])
+    z[0::2] = np.cos(x2pi) * g2rad
+    z[1::2] = np.sin(x2pi) * g2rad
+    return z
+
+
+def _replay_compute(cfg, n: int, seed: int, iters: int):
+    """``iters`` calls of ``ComputeModel(cfg, n, seed).sample()`` drawn in
+    bulk. Returns ``(times, spiking, draws, serial)``: the ``(iters, n)``
+    samples, the ``(iters, n)`` spike multipliers as ``sample`` leaves
+    them in ``spiking`` (0.0 where healthy), the uniforms drawn after the
+    locality draws, and how many spike-chain events (entries and exit
+    checks) were resolved one at a time.
+
+    Rank-sample ``k = it * n + r`` draws one uniform for its spike check
+    (an entry check while healthy, an exit check while spiking), one more
+    for the heavy-tail choice on an entry, and on even ``k`` the two
+    uniforms of a Box-Muller pair (odd ``k`` uses the cached second
+    value). So its check sits at ``2k + (k odd) + E``, ``E`` the entries
+    before it. Only the spike chain is serial: entries, found in ``k``
+    order among the uniforms below ``spike_prob`` under the current
+    shift ``E``, and each spiking rank's exit checks, resolved up to the
+    next entry (the shift holds until then). The rest is vector
+    arithmetic over the draws less the heavy-tail ones, in ``sample``'s
+    operation order."""
+    cm = ComputeModel(cfg, n, seed=seed)
+    mt = _take_over(cm.rng)
+    N = n * iters
+    base = N + 2 * ((N + 1) // 2)          # draws with no spike entry
+    u = mt.random_sample(base + HEAVY_MARGIN)
+    cand = np.flatnonzero(u < cfg.spike_prob).tolist()
+    exit_prob = cfg.spike_exit_prob
+    heavies: List[int] = []        # buffer positions of heavy-tail draws
+    spells: List[tuple] = []       # (first k, end k, multiplier)
+    open_: Dict[int, tuple] = {}   # spiking rank -> (first k, next check, m)
+    shift = serial = i = 0
+    last = -1                      # the last rank-sample resolved
+
+    def exits(upto: int) -> int:
+        """Every spiking rank's exit checks below rank-sample ``upto``."""
+        steps = 0
+        for r, (k0, k, m) in list(open_.items()):
+            while k < upto:
+                steps += 1
+                if u[2 * k + (k & 1) + shift] < exit_prob:
+                    spells.append((k0, k, m))
+                    del open_[r]
+                    break
+                k += n
+            else:
+                open_[r] = (k0, k, m)
+        return steps
+
+    while True:
+        k = N                          # the next check below spike_prob
+        while i < len(cand):
+            q = cand[i] - shift
+            i += 1
+            if q & 3 in (0, 3) and q >> 1 > last:  # a check not yet passed
+                k = min(q >> 1, N)
+                break
+        serial += exits(k)
+        if k == N:
+            break
+        last = k
+        if k % n in open_:             # the rank spikes: an exit check
+            serial += exits(k + 1)
+            continue
+        serial += 1
+        p = 2 * k + (k & 1) + shift + 1  # the heavy-tail draw
+        m = cfg.heavy_mult if u[p] < cfg.heavy_frac else cfg.spike_mult
+        heavies.append(p)
+        shift += 1
+        if u.shape[0] < base + shift:  # the margin ran out: draw on
+            more = mt.random_sample(max(HEAVY_MARGIN, u.shape[0] // 4))
+            cand += (np.flatnonzero(more < cfg.spike_prob)
+                     + u.shape[0]).tolist()
+            u = np.concatenate((u, more))
+        if m:
+            open_[k % n] = (k, k + n, m)
+    spells += [(k0, N, m) for k0, _, m in open_.values()]
+
+    # less the heavy-tail draws, each pair of rank-samples draws 4:
+    # check, x, y (even k), check (odd k)
+    v = np.delete(u[:base + shift], heavies)
+    z = _box_muller(v[1::4], v[2::4])[:N]
+    times = z.reshape(iters, n) * cfg.jitter_sigma
+    np.exp(times, out=times)
+    times *= cm._scale
+    spiking = np.zeros(N)
+    flat = times.reshape(N)
+    for k0, k1, m in spells:
+        spiking[k0:k1:n] = m
+        flat[k0:k1:n] *= m
+    return times, spiking.reshape(iters, n), base + shift, serial
+
 
 def _compute_stream(cfg, n: int, seed: int, iters: int) -> np.ndarray:
-    """Replay ``ComputeModel.sample`` for ``iters`` iterations —
-    bit-identical to the stream the reference engine consumes (the model
-    holds no engine-fed state). Cached by (config, n, seed); the stream
+    """``ComputeModel.sample`` for ``iters`` iterations, as the reference
+    engine consumes it (the model holds no engine-fed state), replayed in
+    bulk (:func:`_replay_compute`): the same draws and spike states,
+    float64 values within 2 ulps. Cached by (config, n, seed); the stream
     is prefix-stable, so a longer request regenerates once."""
     with telemetry.span("fabric.prep.compute_stream"):
         key = (cfg, n, seed)
         hit = _COMPUTE_CACHE.get(key)
         if hit is None or hit.shape[0] < iters:
             telemetry.count("fabric.compute_stream.miss")
-            cm = ComputeModel(cfg, n, seed=seed)
-            hit = np.array([cm.sample() for _ in range(iters)],
-                           dtype=np.float64)
+            hit, _, _, serial = _replay_compute(cfg, n, seed, iters)
+            telemetry.count("fabric.compute_stream.serial_steps", serial)
             _COMPUTE_CACHE[key] = hit
         else:
             telemetry.count("fabric.compute_stream.hit")
@@ -122,31 +247,19 @@ def _compute_stream(cfg, n: int, seed: int, iters: int) -> np.ndarray:
 
 def _gauss_stream(seed: int, count: int) -> np.ndarray:
     """The congestion AR(1) innovation stream: the engine's inlined
-    Box-Muller draws (``CongestionModel.advance``) replayed verbatim,
-    including the sin/cos pair cache carried across ``advance()`` calls —
-    bit-identical regardless of how the stream splits across iterations
-    or how ``random.gauss`` evolves between Python versions."""
+    Box-Muller draws (``CongestionModel.advance``), with the sin/cos pair
+    cache carried across ``advance()`` calls, replayed in bulk: the same
+    uniforms of ``random.Random(seed)``, float64 values within 2 ulps,
+    regardless of how the stream splits across iterations or how
+    ``random.gauss`` evolves between Python versions."""
     with telemetry.span("fabric.prep.gauss_stream"):
         key = (seed,)
         hit = _GAUSS_CACHE.get(key)
         if hit is None or hit.shape[0] < count:
             telemetry.count("fabric.gauss_stream.miss")
-            rnd = random.Random(seed).random
-            cos, sin, log, sqrt = math.cos, math.sin, math.log, math.sqrt
-            twopi = 2.0 * math.pi
-            out = np.empty(count, dtype=np.float64)
-            g_next = None
-            for i in range(count):
-                z = g_next
-                if z is None:
-                    x2pi = rnd() * twopi
-                    g2rad = sqrt(-2.0 * log(1.0 - rnd()))
-                    z = cos(x2pi) * g2rad
-                    g_next = sin(x2pi) * g2rad
-                else:
-                    g_next = None
-                out[i] = z
-            _GAUSS_CACHE[key] = hit = out
+            u = _take_over(random.Random(seed)).random_sample(
+                2 * ((count + 1) // 2))
+            _GAUSS_CACHE[key] = hit = _box_muller(u[0::2], u[1::2])[:count]
         else:
             telemetry.count("fabric.gauss_stream.hit")
         return hit[:count]

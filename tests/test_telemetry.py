@@ -118,6 +118,41 @@ def test_fresh_seed_grid_misses_every_cache_and_grows_them():
     assert snap.caches["fabric.runners.entries"] >= 1
 
 
+def test_compute_stream_serial_steps_are_counted_per_miss():
+    """Entries plus exit checks of the spike chain, on a miss only: the
+    Python model's own spike states give the count independently."""
+    from repro.fabric.backend.jnp_engine import _compute_stream
+    from repro.fabric.stragglers import ComputeModel, StragglerConfig
+
+    heavy = StragglerConfig(spike_prob=0.3, spike_exit_prob=0.05,
+                            heavy_frac=0.5)
+    telemetry.enable()
+    _compute_stream(heavy, 8, 700_601, 40)
+    c = telemetry.take().counters
+    cm = ComputeModel(heavy, 8, seed=700_601)
+    before = np.zeros(8)
+    exit_checks = entries = 0
+    for _ in range(40):
+        cm.sample()
+        after = np.array(cm.spiking)
+        exit_checks += int(np.count_nonzero(before))
+        entries += int(np.count_nonzero((before == 0) & (after != 0)))
+        before = after
+    assert c["fabric.compute_stream.miss"] == 1
+    assert c["fabric.compute_stream.serial_steps"] == \
+        entries + exit_checks > 0
+
+    _compute_stream(heavy, 8, 700_601, 40)
+    c = telemetry.take().counters
+    assert c["fabric.compute_stream.hit"] == 1
+    assert "fabric.compute_stream.serial_steps" not in c
+
+    _compute_stream(StragglerConfig(spike_prob=0.0), 8, 700_602, 40)
+    c = telemetry.take().counters
+    assert c["fabric.compute_stream.miss"] == 1
+    assert c["fabric.compute_stream.serial_steps"] == 0
+
+
 def test_series_are_bit_identical_with_telemetry_on_and_off():
     off = _grid(700_401, fresh_seeds=True).run(backend="jnp")
     telemetry.enable()
