@@ -1,13 +1,14 @@
 """Read the control at a cell's own size: the plain reference computed in
 bfloat16, held to the float64 reference by the cell's own comparison.
-Its smallest reading is the upper end a limit may take.
+Its smallest reading of each number is the upper end that number's
+limit may take.
 
     python3 bench/control.py --workload <cell> --seeds 1 2 3
 
 For every seed it checks ``checked`` variants of the seed's first sweep,
 drawn from the seed, and prints the twin horizon, the span compared and
-the control's widest relative gap. It runs on the host alone; the benchmark's runs do not run
-it.
+each number the check reads of the control. It runs on the host
+alone; the benchmark's runs do not run it.
 """
 import argparse
 import os
@@ -32,7 +33,8 @@ def readings(name: str, seed: int):
         params, scn = every[k]
         want, horizon, span = check.reference_span(scn, limits["departure"])
         got = check.control_rows(scn, span)
-        yield params, horizon, span, check.worst_rel(got, want)
+        yield params, horizon, span, {k: read(got, want) for k, read
+                                      in check.NUMBERS.items()}
 
 
 def main() -> None:
@@ -40,14 +42,17 @@ def main() -> None:
     p.add_argument("--workload", required=True)
     p.add_argument("--seeds", type=int, nargs="+", required=True)
     args = p.parse_args()
-    worst = []
+    seen = {k: [] for k in check.NUMBERS}
     for seed in args.seeds:
-        for params, horizon, span, w in readings(args.workload, seed):
-            worst.append(w)
+        for params, horizon, span, got in readings(args.workload, seed):
+            for k in check.NUMBERS:
+                seen[k].append(got[k])
             print(f"seed={seed} {params} horizon={horizon} span={span} "
-                  f"control_worst_rel_iter={w!r}", flush=True)
-    print(f"control {args.workload}: min={min(worst)!r} max={max(worst)!r} "
-          f"over {len(worst)} variants")
+                  + " ".join(f"control_{k}={got[k]!r}" for k in check.NUMBERS),
+                  flush=True)
+    for k in check.NUMBERS:
+        print(f"control {args.workload} {k}: min={min(seen[k])!r} "
+              f"max={max(seen[k])!r} over {len(seen[k])} variants")
 
 
 if __name__ == "__main__":

@@ -216,28 +216,30 @@ def run(name: str, seed: int, seconds: float, traced: bool,
 
 def _check(scenario, traffic, seed, limits, kept, failed):
     """Compare ``limits["checked"]`` of the kept variants, drawn from the
-    seed, with the reference; returns the numbers compared beside their
-    limits."""
+    seed, with the reference; returns each number the cell's limits name,
+    the worst over the variants, beside its limit."""
     rng = random.Random(f"{seed}:check")
-    worst, detail = 0.0, []
+    detail = []
     for (i, bs, k, params, got) in rng.sample(
             kept, min(limits["checked"], len(kept))):
         p_ref, scn = cells.variants(scenario, cells.sweep_axes(traffic, bs),
                                     bs)[k]
         if p_ref != params:
-            c = {"horizon": 0, "span": 0, "worst_rel_iter": float("inf")}
+            c = {"horizon": 0, "span": 0,
+                 **dict.fromkeys(check.NUMBERS, float("inf"))}
         else:
             c = check.compare(scn, got, limits["departure"])
-        worst = max(worst, c["worst_rel_iter"])
         detail.append(dict(c, sweep=i, variant=k,
                            params=",".join(f"{p.split('.')[-1]}={x}"
                                            for p, x in params.items())))
-    lim = limits["limits"]
-    worst = min(worst, sys.float_info.max)      # JSON has no infinity
-    return {"worst_rel_iter": {"value": worst,
-                               "limit": lim["worst_rel_iter"]},
-            "failed_variants": {"value": failed,
-                                "limit": lim["failed_variants"]}}, detail
+    checks = {}
+    for key, limit in limits["limits"].items():
+        value = failed if key == "failed_variants" else \
+            max([c[key] for c in detail], default=0.0)
+        # JSON has no infinity
+        checks[key] = {"value": min(value, sys.float_info.max),
+                       "limit": limit}
+    return checks, detail
 
 
 def _per_layer(spec, spans, sweeps, n_var, per_sweep, trace_dir):

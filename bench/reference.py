@@ -8,14 +8,17 @@ placement, per-link AR(1) background congestion with the arrival-burst
 derate and the queue-buildup kick, and co-tenant sharing of each shared
 link by ``maxmin``, ``wfq`` or ``strict_priority`` over the tenants whose
 collectives (this round's, or a recorded busy segment of an earlier one)
-overlap the owner's window. It imports nothing of the program and reads a
-scenario as the plain dict a configuration file holds
+overlap the owner's window. A job with ``pacing`` runs the paper's
+bounded pacing (sections 4.3 and 5.3): one release clock and one
+controller per rank (:class:`Pacer`). It imports nothing of the program
+and reads a scenario as the plain dict a configuration file holds
 (``Scenario.to_dict`` form). Anything outside that envelope raises
 ``ValueError``.
 
 ``quantize`` rounds every per-step quantity (compute samples, link
-utilization, efficiencies, shares, collective and step times) to a lower
-precision: the control the checks must fail. Clocks stay in float64.
+utilization, efficiencies, shares, collective and step times, a
+controller's observations and delays) to a lower precision: the control
+the checks must fail. Clocks stay in float64.
 """
 from __future__ import annotations
 
@@ -121,7 +124,6 @@ def _check(scn: dict) -> None:
             ("algo", job["algo"] == "ring"),
             ("placement", job["placement"] in ("compact", "striped")),
             ("nodes", job["nodes"] is None),
-            ("pacing", job["pacing"] is None),
             ("seed", job["seed"] is None))
             if not ok]
         if bad:
@@ -236,6 +238,68 @@ def _gauss(rnd, g_next):
     return math.cos(x2pi) * g2rad, math.sin(x2pi) * g2rad
 
 
+def _median(xs: List[float]) -> float:
+    s = sorted(xs)
+    n = len(s)
+    return s[n // 2] if n % 2 else 0.5 * (s[n // 2 - 1] + s[n // 2])
+
+
+class Pacer:
+    """One rank's bounded-pacing controller. It sees only its own barrier
+    wait and step time. Over a rolling window of ``window`` waits,
+    earliness values (wait plus the delay it held) and steps, once
+    ``warmup_iters`` observations and two columns are in: where the
+    median wait, or a high wait CV with the newest wait, is above the
+    thresholds and every earliness in the window is positive, the delay
+    becomes ``gain`` times the smallest earliness; otherwise it decays
+    by ``decay``, to zero below a millionth of the median step. The rank
+    is held back by that delay, at most ``max_delay_frac`` times the
+    median step."""
+
+    def __init__(self, cfg: dict, q: Callable[[float], float]):
+        self.cfg = cfg
+        self.q = q
+        self.waits: List[float] = []
+        self.early: List[float] = []
+        self.steps: List[float] = []
+        self.delay = 0.0                # held delay, before the bound
+        self.seen = 0
+
+    def observe(self, wait: float, step: float) -> None:
+        q, w = self.q, self.cfg["window"]
+        wait = q(wait if wait > 0.0 else 0.0)
+        self.waits = (self.waits + [wait])[-w:]
+        self.early = (self.early + [q(wait + self.delay)])[-w:]
+        self.steps = (self.steps + [q(step if step > 0.0 else 0.0)])[-w:]
+        self.seen += 1
+
+    def decide(self) -> float:
+        """The delay before this rank's next release."""
+        cfg, q, waits = self.cfg, self.q, self.waits
+        if not cfg["enabled"] or self.seen < cfg["warmup_iters"] \
+                or len(waits) < 2:
+            return 0.0
+        n = len(waits)
+        mean = sum(waits) / n
+        cv = 0.0
+        if mean > 0.0:
+            cv = math.sqrt(sum((x - mean) * (x - mean)
+                               for x in waits) / n) / mean
+        med_wait, med_step = _median(waits), _median(self.steps)
+        rel_med = med_wait / med_step if med_step > 0.0 else 0.0
+        rel_last = waits[-1] / med_step if med_step > 0.0 else 0.0
+        imbalanced = rel_med > cfg["skew_threshold"] or (
+            cv > cfg["cv_threshold"] and rel_last > cfg["skew_threshold"])
+        least = min(self.early)
+        if imbalanced and least > 0.0:
+            self.delay = q(cfg["gain"] * least)
+        else:
+            self.delay = q(self.delay * cfg["decay"])
+            if self.delay < 1e-6 * max(med_step, 1e-9):
+                self.delay = 0.0
+        return q(min(self.delay, cfg["max_delay_frac"] * med_step))
+
+
 def _activity(users, s_i: float, e_i: float, win, seg: np.ndarray,
               seg_ov: np.ndarray) -> Dict[int, float]:
     """Per co-tenant owner, the time its traffic overlaps the window
@@ -316,14 +380,25 @@ def steps(scn: dict, quantize: Optional[Callable[[float], float]] = None
     segments: List[tuple] = []
     release = [0.0] * J
     prev = [0.0] * J
+    # a paced job releases each rank on its own clock
+    pacers = [None if s["pacing"] is None
+              else [Pacer(s["pacing"], q) for _ in range(s["n_ranks"])]
+              for s in specs]
+    rank_release = [[0.0] * s["n_ranks"] for s in specs]
+    arrival: List[List[float]] = [[] for _ in specs]
 
     for t in range(scn["iters"]):
         # 1. arrival windows
         first, last, skew = [], [], []
         for j in range(J):
             c = [q(x) for x in comps[j].sample()]
-            first.append(release[j] + min(c))
-            last.append(release[j] + max(c))
+            if pacers[j] is None:
+                first.append(release[j] + min(c))
+                last.append(release[j] + max(c))
+            else:
+                arrival[j] = [r + x for r, x in zip(rank_release[j], c)]
+                first.append(min(arrival[j]))
+                last.append(max(arrival[j]))
             skew.append((last[j] - first[j]) / floor[j])
         # 2. background congestion, one AR(1) step per shared link
         for ln in shared:
@@ -385,6 +460,13 @@ def steps(scn: dict, quantize: Optional[Callable[[float], float]] = None
             finish = last[j] + dur[j]
             out[j] = q(finish - prev[j] if t > 0 else finish)
             prev[j] = release[j] = finish
+            if pacers[j] is not None:
+                # each rank waited from its arrival to the last one's,
+                # and stepped from its own release to the finish
+                for pacer, a, r in zip(pacers[j], arrival[j],
+                                       rank_release[j]):
+                    pacer.observe(last[j] - a, finish - r)
+                rank_release[j] = [finish + p.decide() for p in pacers[j]]
         yield out
 
 

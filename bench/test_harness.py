@@ -13,23 +13,19 @@ import pytest
 from bench import cells, harness, reference, trace as tr
 
 ROOT = cells.ROOT
+CELLS = sorted(w["name"] for w in cells.benchmark()["workloads"])
 
 
-def shrink_calibrate(scn, traffic):
+def shrink(scn, traffic):
+    """A cell at test scale: 30 iterations (5 of them warm-up), the first
+    and the last value of each axis, and 4 seeds on a seed axis."""
     scn = json.loads(json.dumps(scn))
     scn["iters"], scn["warmup"] = 30, 5
-    return scn, dict(traffic, axes={"congestion.u_mean": [0.05, 0.1],
-                                    "congestion.u_sigma": [0.04, 0.16]})
-
-
-def shrink_seeds(scn, traffic):
-    scn = json.loads(json.dumps(scn))
-    scn["iters"], scn["warmup"] = 30, 5
-    return scn, dict(traffic, seed_axis=dict(traffic["seed_axis"], count=4))
-
-
-SHRINK = {"table1_n64.calibrate_grid": shrink_calibrate,
-          "table1_n64.seed_grid": shrink_seeds}
+    traffic = dict(traffic, axes={k: v[:1] + v[-1:]
+                                  for k, v in traffic["axes"].items()})
+    if "seed_axis" in traffic:
+        traffic["seed_axis"] = dict(traffic["seed_axis"], count=4)
+    return scn, traffic
 
 
 def cotenant(fairness):
@@ -52,7 +48,7 @@ def cotenant(fairness):
 def run_small(name, traced=False, seed=2 ** 31 + 12345, trace_dir=None):
     import jax
     return harness.run(name, seed, 0.3, traced, jax.devices()[0], 0.0,
-                       shrink=SHRINK[name], trace_dir=trace_dir)
+                       shrink=shrink, trace_dir=trace_dir)
 
 
 # -- trace reduction --------------------------------------------------------
@@ -115,24 +111,30 @@ def test_trace_reads_device_lines_by_name():
 # -- cells ------------------------------------------------------------------
 
 
-def _expected(name):
-    """What the cell files should build: Table 1's 64-node baseline as
-    the repository fits it, and the grid ``calibrate()`` sweeps around a
-    fit by default (or that configuration as it stands)."""
+# Table 1's 64-node runs as the repository fits them, by configuration
+PAPER = {"table1_n64": {"coordination": False},
+         "table1_n64_paced": {"coordination": True}}
+
+
+def _expected(spec):
+    """What the cell files should build: the configuration's
+    ``SimConfig.paper`` scenario, and the grid ``calibrate()`` sweeps
+    around a fit by default (or that configuration as it stands)."""
     from repro.fabric import SimConfig, scenario_from
-    base = scenario_from(SimConfig.paper(64, coordination=False, seed=0))
-    if name == "table1_n64.calibrate_grid":
+    base = scenario_from(SimConfig.paper(64, seed=0,
+                                         **PAPER[spec["config"]]))
+    if spec["traffic"] == "calibrate_grid":
         u = base.congestion.u_mean
         return base, {"congestion.u_mean": sorted({u * 0.5, u, u * 1.5}),
-                      "congestion.u_sigma": [0.04, 0.08, 0.16]}
-    return base, {}
+                      "congestion.u_sigma": [0.04, 0.08, 0.16]}, 9
+    return base, {}, 64
 
 
-@pytest.mark.parametrize("name", sorted(SHRINK))
+@pytest.mark.parametrize("name", CELLS)
 def test_cell_files_build_the_repository_grids(name):
     from repro.fabric.scenario import Scenario, ScenarioGrid
     spec = cells.cell(name)
-    base, axes = _expected(name)
+    base, axes, n_variants = _expected(spec)
     got = Scenario.from_dict(spec["config_data"]["scenario"])
     assert dataclasses.replace(got, name=base.name) == base
     traffic = spec["traffic_data"]
@@ -143,8 +145,7 @@ def test_cell_files_build_the_repository_grids(name):
     sweep = cells.sweep_axes(traffic, bs)
     mine = cells.variants(spec["config_data"]["scenario"], sweep, bs)
     want = ScenarioGrid(base.replace(base_seed=bs), sweep)
-    assert len(mine) == len(want) == {"table1_n64.calibrate_grid": 9,
-                                      "table1_n64.seed_grid": 64}[name]
+    assert len(mine) == len(want) == n_variants
     for (p, d), (q, s) in zip(mine, want):
         assert p == q
         assert dataclasses.replace(Scenario.from_dict(d), name=s.name) == s
@@ -194,22 +195,57 @@ def test_run_exits_nonzero_without_a_tpu():
 # -- the plain reference ----------------------------------------------------
 
 
-@pytest.mark.parametrize("fairness", ["maxmin", "wfq", "strict_priority",
-                                      "single"])
-def test_plain_reference_is_the_engine_bit_for_bit(fairness):
+FAIRNESS = ["maxmin", "wfq", "strict_priority", "single"]
+
+
+@pytest.mark.parametrize(
+    "fairness,seed",
+    [pytest.param(f, 4242, id=f) for f in FAIRNESS]
+    + [pytest.param(f, 2 ** 31 - 7, id=f"{f}-high_seed") for f in FAIRNESS])
+def test_plain_reference_is_the_engine_bit_for_bit(fairness, seed):
     from repro.fabric.scenario import Scenario
     if fairness == "single":                    # the cells' own job
-        name = "table1_n64.calibrate_grid"
-        scn, _ = SHRINK[name](cells.cell(name)["config_data"]["scenario"],
-                              {})
-        scn["iters"], scn["base_seed"] = 60, 4242
+        scn = cells.cell("table1_n64.calibrate_grid")["config_data"]
+        scn = json.loads(json.dumps(scn["scenario"]))
+        scn["iters"], scn["warmup"] = 60, 5
     else:
         scn = cotenant(fairness)
+    scn["base_seed"] = seed
     res = Scenario.from_dict(scn).run(backend="reference")
     want = np.stack([res.series(j["name"]) for j in scn["jobs"]], axis=1)
     got = reference.series(scn)
     assert got.shape == want.shape
     assert np.array_equal(got, want)
+
+
+def _paced(case, seed):
+    """The paced cell's job at test scale: alone, or cut to 32 ranks
+    beside an unpaced copy of itself on the other half of the fabric,
+    the two sharing the spine."""
+    spec = cells.cell("table1_n64_paced.calibrate_grid")
+    scn, _ = shrink(spec["config_data"]["scenario"], spec["traffic_data"])
+    if case == "beside_unpaced":
+        paced = dict(scn["jobs"][0], n_ranks=32)
+        scn["jobs"] = [paced, dict(paced, name="unpaced", pacing=None)]
+    scn["base_seed"] = seed
+    return scn
+
+
+@pytest.mark.parametrize("seed", [4242, 2 ** 31 - 7])
+@pytest.mark.parametrize("case", ["single", "beside_unpaced"])
+def test_paced_reference_is_the_engine_bit_for_bit(case, seed):
+    from repro.fabric.scenario import Scenario
+    scn = _paced(case, seed)
+    res = Scenario.from_dict(scn).run(backend="reference")
+    want = np.stack([res.series(j["name"]) for j in scn["jobs"]], axis=1)
+    got = reference.series(scn)
+    assert got.shape == want.shape
+    assert np.array_equal(got, want)
+    paced = [j for j, s in enumerate(scn["jobs"]) if s["pacing"]]
+    for s in scn["jobs"]:
+        s["pacing"] = None
+    unpaced = reference.series(scn)
+    assert not np.array_equal(got[:, paced], unpaced[:, paced])
 
 
 def test_plain_reference_refuses_what_it_does_not_model():
@@ -223,7 +259,7 @@ def test_plain_reference_refuses_what_it_does_not_model():
 # -- a whole run at test scale ----------------------------------------------
 
 
-@pytest.mark.parametrize("name", sorted(SHRINK))
+@pytest.mark.parametrize("name", CELLS)
 def test_cpu_rehearsal_of_a_run(name, tmp_path):
     out = run_small(name)
     assert out["correct"] is True
