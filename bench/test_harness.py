@@ -1,6 +1,7 @@
 """Tests of the benchmark harness, at test scale on the CPU."""
 import dataclasses
 import json
+import math
 import os
 import subprocess
 import sys
@@ -17,12 +18,13 @@ CELLS = sorted(w["name"] for w in cells.benchmark()["workloads"])
 
 
 def shrink(scn, traffic):
-    """A cell at test scale: 30 iterations (5 of them warm-up), the first
-    and the last value of each axis, and 4 seeds on a seed axis."""
+    """A cell at test scale: 30 iterations (5 of them warm-up), every
+    value of every axis, and 4 seeds on a seed axis. No axis is cut, so
+    that two of its values that wrongly share a compiled runner show
+    here."""
     scn = json.loads(json.dumps(scn))
     scn["iters"], scn["warmup"] = 30, 5
-    traffic = dict(traffic, axes={k: v[:1] + v[-1:]
-                                  for k, v in traffic["axes"].items()})
+    traffic = dict(traffic)
     if "seed_axis" in traffic:
         traffic["seed_axis"] = dict(traffic["seed_axis"], count=4)
     return scn, traffic
@@ -111,44 +113,68 @@ def test_trace_reads_device_lines_by_name():
 # -- cells ------------------------------------------------------------------
 
 
-# Table 1's 64-node runs as the repository fits them, by configuration
-PAPER = {"table1_n64": {"coordination": False},
-         "table1_n64_paced": {"coordination": True}}
-
-
-def _expected(spec):
-    """What the cell files should build: the configuration's
-    ``SimConfig.paper`` scenario, and the grid ``calibrate()`` sweeps
-    around a fit by default (or that configuration as it stands)."""
-    from repro.fabric import SimConfig, scenario_from
-    base = scenario_from(SimConfig.paper(64, seed=0,
-                                         **PAPER[spec["config"]]))
-    if spec["traffic"] == "calibrate_grid":
-        u = base.congestion.u_mean
-        return base, {"congestion.u_mean": sorted({u * 0.5, u, u * 1.5}),
-                      "congestion.u_sigma": [0.04, 0.08, 0.16]}, 9
-    return base, {}, 64
-
-
-@pytest.mark.parametrize("name", CELLS)
-def test_cell_files_build_the_repository_grids(name):
+def check_cell_files(spec):
+    """What a cell's own files have to hold together, whatever the
+    deployment: the configuration is a ``Scenario`` as it is written
+    (``to_dict()`` gives the file back), the harness's variants are
+    ``ScenarioGrid``'s (which raises on an axis path that resolves to no
+    field) and its count of tenant-iterations is theirs."""
     from repro.fabric.scenario import Scenario, ScenarioGrid
-    spec = cells.cell(name)
-    base, axes, n_variants = _expected(spec)
-    got = Scenario.from_dict(spec["config_data"]["scenario"])
-    assert dataclasses.replace(got, name=base.name) == base
+    scn = spec["config_data"]["scenario"]
+    base = Scenario.from_dict(scn)
+    assert base.to_dict() == scn
     traffic = spec["traffic_data"]
-    assert list(traffic["axes"]) == list(axes)
-    for k, v in axes.items():
-        assert traffic["axes"][k] == pytest.approx(v)
     bs = cells.base_seed(1, 0, traffic["seeds"])
     sweep = cells.sweep_axes(traffic, bs)
-    mine = cells.variants(spec["config_data"]["scenario"], sweep, bs)
+    mine = cells.variants(scn, sweep, bs)
     want = ScenarioGrid(base.replace(base_seed=bs), sweep)
-    assert len(mine) == len(want) == n_variants
+    assert len(mine) == len(want) == math.prod(len(v)
+                                               for v in sweep.values())
     for (p, d), (q, s) in zip(mine, want):
         assert p == q
         assert dataclasses.replace(Scenario.from_dict(d), name=s.name) == s
+    # an axis that changes the jobs or their length changes the count
+    assert cells.tenant_iters(scn, len(mine)) == sum(
+        len(d["jobs"]) * d["iters"] for _, d in mine)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_files_build_their_grids(name):
+    check_cell_files(cells.cell(name))
+
+
+# Table 1's 64-node runs as the repository fits them, by configuration
+PAPER = {"table1_n64": {"coordination": False},
+         "table1_n64_paced": {"coordination": True}}
+TABLE1 = [n for n in CELLS if cells.cell(n)["config"] in PAPER]
+
+
+@pytest.mark.parametrize("name", TABLE1)
+def test_cell_files_build_the_repository_grids(name):
+    """A cell on one of Table 1's configurations is that configuration's
+    ``SimConfig.paper`` scenario; its ``calibrate_grid`` is the grid
+    ``calibrate()`` sweeps around a fit by default, its ``seed_grid``
+    the configuration as it stands over 64 seeds."""
+    from repro.fabric import SimConfig, scenario_from
+    from repro.fabric.scenario import Scenario
+    spec = cells.cell(name)
+    base = scenario_from(SimConfig.paper(64, seed=0,
+                                         **PAPER[spec["config"]]))
+    got = Scenario.from_dict(spec["config_data"]["scenario"])
+    assert dataclasses.replace(got, name=base.name) == base
+    traffic = spec["traffic_data"]
+    if spec["traffic"] == "calibrate_grid":
+        u = base.congestion.u_mean
+        axes = {"congestion.u_mean": sorted({u * 0.5, u, u * 1.5}),
+                "congestion.u_sigma": [0.04, 0.08, 0.16]}
+        assert list(traffic["axes"]) == list(axes)
+        for k, v in axes.items():
+            assert traffic["axes"][k] == pytest.approx(v)
+        assert "seed_axis" not in traffic
+    elif spec["traffic"] == "seed_grid":
+        assert traffic["axes"] == {}
+        assert traffic["seed_axis"]["path"] == "base_seed"
+        assert traffic["seed_axis"]["count"] == 64
 
 
 def test_benchmark_json_names_every_file():
@@ -160,9 +186,9 @@ def test_benchmark_json_names_every_file():
         cells.cell(w["name"])                      # loads all three files
 
 
-@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 5, 2 ** 33 - 1])
-def test_sweep_seeds_never_share_a_stream(seed):
-    traffic = cells.cell("table1_n64.seed_grid")["traffic_data"]
+def check_seeds(traffic, seed):
+    """No two sweeps of a run, and no two variants of a sweep, share a
+    random stream."""
     seeds = traffic["seeds"]
     got = [cells.base_seed(seed, i, seeds) for i in range(-1, 3000)]
     assert all(0 <= b < 2 ** 31 for b in got)
@@ -178,6 +204,15 @@ def test_sweep_seeds_never_share_a_stream(seed):
     assert 2 < traffic["seed_axis"]["stride"]
     assert traffic["seed_axis"]["stride"] * traffic["seed_axis"]["count"] \
         <= seeds["stride"]
+
+
+SEEDED = [n for n in CELLS if "seed_axis" in cells.cell(n)["traffic_data"]]
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 5, 2 ** 33 - 1])
+@pytest.mark.parametrize("name", SEEDED)
+def test_sweep_seeds_never_share_a_stream(name, seed):
+    check_seeds(cells.cell(name)["traffic_data"], seed)
 
 
 def test_run_exits_nonzero_without_a_tpu():
@@ -259,8 +294,9 @@ def test_plain_reference_refuses_what_it_does_not_model():
 # -- a whole run at test scale ----------------------------------------------
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_cpu_rehearsal_of_a_run(name, tmp_path):
+def rehearse(name, trace_dir):
+    """Run cell ``name`` at test scale, plain and traced, and check what
+    each run reports."""
     out = run_small(name)
     assert out["correct"] is True
     assert out["failed"] == 0 and out["attempted"] > 0
@@ -270,12 +306,61 @@ def test_cpu_rehearsal_of_a_run(name, tmp_path):
     assert list(out)[-1] == "checks"
     assert out["window"]["compiles"] == 0
     assert out["device"]["platform"] == "cpu"
-    traced = run_small(name, traced=True, trace_dir=str(tmp_path))
+    traced = run_small(name, traced=True, trace_dir=str(trace_dir))
     assert traced["correct"] is True
-    assert {"prep_us_per_variant", "wrap_us_per_variant"} \
-        <= set(traced["metrics"])
+    assert {m["name"] for m in cells.cell(name)["per_layer"]
+            if m["source"] == "program_span"} <= set(traced["metrics"])
     assert traced["device"]["window_s"] > 0
     assert {"device_ops", "idle_gaps"} <= set(traced["breakdown"])
+    return traced
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cpu_rehearsal_of_a_run(name, tmp_path):
+    rehearse(name, tmp_path)
+
+
+@pytest.mark.parametrize("donor", ["table1_n64_paced.calibrate_grid",
+                                   "table1_n64.seed_grid"])
+def test_a_cell_joins_by_its_files_alone(donor, tmp_path, monkeypatch):
+    """A copy of ``donor`` under new names, added as new files and new
+    ``BENCHMARK.json`` entries and nothing else, is checked and run by
+    what its files state."""
+    spec = cells.cell(donor)
+    bench = cells.benchmark()
+    conf = next(c for c in bench["configs"] if c["name"] == spec["config"])
+    entry = next(w for w in bench["workloads"] if w["name"] == donor)
+    name = "joined.joined_mix"
+    files = {"configs/joined.json": spec["config_data"],
+             "traffic/joined_mix.json": spec["traffic_data"],
+             f"limits/{name}.json": spec["limits"]}
+    for path, data in files.items():
+        (tmp_path / "bench" / path).parent.mkdir(parents=True, exist_ok=True)
+        (tmp_path / "bench" / path).write_text(json.dumps(data))
+    bench["configs"].append(dict(conf, name="joined",
+                                 file="bench/configs/joined.json"))
+    bench["workloads"].append(dict(entry, name=name,
+                                   config="joined", traffic="joined_mix"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    monkeypatch.setattr(cells, "ROOT", str(tmp_path))
+    monkeypatch.setattr(cells, "HERE", str(tmp_path / "bench"))
+
+    joined = cells.cell(name)
+    assert joined["config_data"] == spec["config_data"]
+    # though no entry already in BENCHMARK.json names it, the joined cell
+    # reports each of its donor's per-layer metrics, and each end-to-end
+    # metric that lists no cells
+    assert [m["name"] for m in joined["end_to_end"]] == [
+        m["name"] for m in spec["end_to_end"] if "workloads" not in m]
+    assert [m["name"] for m in joined["per_layer"]] == [
+        m["name"] for m in spec["per_layer"]]
+    check_cell_files(joined)
+    if "seed_axis" in joined["traffic_data"]:
+        check_seeds(joined["traffic_data"], 2 ** 31 + 5)
+    traced = rehearse(name, tmp_path / "trace")
+    spans = {m["name"] for m in spec["per_layer"]
+             if m["source"] == "program_span"}
+    assert spans and spans <= set(traced["metrics"])
 
 
 def test_window_leaves_out_the_harness_bookkeeping(monkeypatch):
