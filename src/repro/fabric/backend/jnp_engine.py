@@ -742,6 +742,9 @@ def _get_runner(sig, static, kernels: KernelType, S: int):
     fn = _RUNNERS.get(key)
     if fn is None:
         telemetry.count("fabric.runner.build")
+        for job in static["jobs"]:
+            if job["pace"] is not None and job["pace"][2]:
+                telemetry.count("fabric.runner.median_network")
         fn = _RUNNERS[key] = _make_runner(static, kernels, S)
     return fn
 

@@ -32,7 +32,7 @@ allocator — ``tests/test_backend.py`` asserts this directly.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -291,6 +291,47 @@ def segment_overlap(s_i, e_i, starts, ends) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def sorting_network(w: int) -> List[Tuple[int, int]]:
+    """Batcher's odd-even merge sort on ``w`` wires, as compare-exchange
+    pairs ``(i, j)`` with ``i < j``: applied in order, each leaves the
+    smaller value on wire ``i``, and at the end wire ``k`` holds the
+    ``k``-th smallest. For ``w`` not a power of two the pairs that would
+    touch a wire past ``w`` are left out (as if those wires held
+    ``+inf``). 12 pairs for ``w = 6``, 191 for 32."""
+    pairs = []
+    p = 1
+    while p < w:
+        k = p
+        while k:
+            for j in range(k % p, w - k, 2 * k):
+                for i in range(min(k, w - j - k)):
+                    if (i + j) // (2 * p) == (i + j + k) // (2 * p):
+                        pairs.append((i + j, i + j + k))
+            k //= 2
+        p *= 2
+    return pairs
+
+
+def _window_medians(bufs, valid, count):
+    """Median of the ``count`` valid entries (mask ``valid`` on the last
+    axis) of each row of ``bufs``: invalid slots go to ``+inf`` and
+    :func:`sorting_network` orders the columns with ``minimum`` and
+    ``maximum``, unrolled at trace time over the static width, in place
+    of a device ``sort`` (an XLA sort of a six-wide row costs far more
+    than the dozen compare-exchanges). ``min``/``max`` return one of
+    their operands, so on inputs without NaN or ``-0.0`` the ranked
+    columns are bit for bit ``jnp.sort``'s."""
+    w = bufs.shape[-1]
+    cols = list(jnp.moveaxis(jnp.where(valid, bufs, jnp.inf), -1, 0))
+    for i, j in sorting_network(w):
+        cols[i], cols[j] = (jnp.minimum(cols[i], cols[j]),
+                            jnp.maximum(cols[i], cols[j]))
+    ranked = jnp.stack(cols[:w // 2 + 1])      # ranks count // 2 reaches
+    hi = ranked[count // 2]
+    lo = ranked[jnp.maximum(count // 2 - 1, 0)]
+    return jnp.where(count % 2 == 1, hi, 0.5 * (lo + hi))
+
+
 def bank_decide(waits, steps, early, delay, pos, count, seen, *,
                 enabled: bool, warmup_iters, cv_threshold, skew_threshold,
                 gain, decay, max_delay_frac
@@ -303,8 +344,10 @@ def bank_decide(waits, steps, early, delay, pos, count, seen, *,
     ``pos``, ``count`` filled columns); ``delay``: the unbounded internal
     per-rank delay state; ``seen``: observations so far. Returns
     ``(bounded_delays, new_internal_delay)``. Mirrors the bank's masked
-    arithmetic: left-to-right window sums in deque order, sorted-row
-    medians, the decay-to-zero cutoff, and the ``max_delay_frac`` bound.
+    arithmetic: left-to-right window sums in deque order, row medians
+    (:func:`_window_medians`), the decay-to-zero cutoff, and the
+    ``max_delay_frac`` bound. The waits and steps have no NaN or
+    ``-0.0`` (the engine clips them at ``+0.0``).
     """
     waits = jnp.asarray(waits, dtype=float)
     n, w = waits.shape
@@ -332,17 +375,8 @@ def bank_decide(waits, steps, early, delay, pos, count, seen, *,
                         jnp.sqrt(var) / jnp.where(mean_pos, mean, 1.0),
                         0.0)
 
-    def rowmedian(buf):
-        srt = jnp.sort(jnp.where(valid, buf, jnp.inf), axis=1)
-        hi = jnp.take_along_axis(
-            srt, jnp.full((n, 1), count // 2), axis=1)[:, 0]
-        lo = jnp.take_along_axis(
-            srt, jnp.full((n, 1), jnp.maximum(count // 2 - 1, 0)),
-            axis=1)[:, 0]
-        return jnp.where(count % 2 == 1, hi, 0.5 * (lo + hi))
-
-    med_wait = rowmedian(wait_o)
-    med_step = rowmedian(step_o)
+    med_wait, med_step = _window_medians(
+        jnp.stack([wait_o, step_o]), valid, count)
     own_wait = waits[:, (pos - 1) % w]       # newest observation
     min_early = early_o.min(axis=1)
 

@@ -249,18 +249,20 @@ def test_segment_overlap_kernel_within_ulp_tier():
 
 
 @needs_jax
-def test_pacing_decide_kernel_within_ulp_tier():
+@pytest.mark.parametrize("window", [6, 32])
+def test_pacing_decide_kernel_within_ulp_tier(window):
     """The jnp kernel consumes the same ``(n, window)`` ring-buffer
     state a live :class:`PacingBank` holds; with the cursor at 0 (whole
     window wraps) the two must agree within the declared ULP budget on
-    both the bounded delays and the carried internal delay state."""
+    both the bounded delays and the carried internal delay state. Window
+    6 is the fitted Table 1 controller's, 32 ``PacingConfig``'s default."""
     from repro.configs.base import PacingConfig
     from repro.core.pacing import PacingBank
 
     tier, tol = EQUIVALENCE_TIERS["pacing_decide"]
     assert tier == "ulp"
     fast = get_kernel("pacing_decide", "jnp")
-    cfg = PacingConfig(enabled=True, window=6, cv_threshold=0.05,
+    cfg = PacingConfig(enabled=True, window=window, cv_threshold=0.05,
                        skew_threshold=0.04, max_delay_frac=0.5, gain=0.8,
                        decay=0.8, warmup_iters=4)
     n = 8
@@ -282,6 +284,115 @@ def test_pacing_decide_kernel_within_ulp_tier():
             got, new_delay = fast(waits, steps, early, delay, seen, cfg)
             assert _within_ulps(np.asarray(got), want, tol)
             assert _within_ulps(np.asarray(new_delay), bank._delay, tol)
+
+
+def _sorted_row_medians(bufs, valid, count):
+    """The window medians as ``bank_decide`` took them before the min/max
+    network: a device sort of each masked row, then ranks ``count // 2``
+    and ``count // 2 - 1``."""
+    import jax.numpy as jnp
+
+    def rowmedian(buf):
+        n = buf.shape[0]
+        srt = jnp.sort(jnp.where(valid, buf, jnp.inf), axis=1)
+        hi = jnp.take_along_axis(
+            srt, jnp.full((n, 1), count // 2), axis=1)[:, 0]
+        lo = jnp.take_along_axis(
+            srt, jnp.full((n, 1), jnp.maximum(count // 2 - 1, 0)),
+            axis=1)[:, 0]
+        return jnp.where(count % 2 == 1, hi, 0.5 * (lo + hi))
+
+    return jnp.stack([rowmedian(b) for b in bufs])
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.dtype, a.shape, a.tobytes()
+
+
+@needs_jax
+@pytest.mark.parametrize("x64", [False, True], ids=["float32", "float64"])
+@pytest.mark.parametrize("w", [2, 3, 5, 6, 7, 8, 32])
+def test_bank_decide_network_medians_are_the_sorted_rows_bit_for_bit(
+        w, x64, monkeypatch):
+    """``bank_decide`` with the min/max network against the same function
+    with the sort-based medians, on traced cursors and counts as the
+    engine's scan passes them: every fill ``count`` from 1 to ``w``,
+    several cursors once full, rows with ties, and garbage in the slots
+    a partial window leaves out (they rank as ``+inf``). Both returned
+    arrays and the medians themselves must match bit for bit."""
+    from repro.fabric.backend import jnp_kernels as K
+
+    kw = dict(enabled=True, warmup_iters=2.0, cv_threshold=0.05,
+              skew_threshold=0.04, gain=0.85, decay=0.8,
+              max_delay_frac=0.1)      # small: the bound reads med_step
+
+    def sorted_decide(*args):
+        with monkeypatch.context() as m:      # in force while tracing
+            m.setattr(K, "_window_medians", _sorted_row_medians)
+            return K.bank_decide(*args, **kw)
+
+    network = jax.jit(lambda *a: K.bank_decide(*a, **kw))
+    sorted_ = jax.jit(sorted_decide)
+    net_med = jax.jit(K._window_medians)
+    srt_med = jax.jit(_sorted_row_medians)
+    rng = np.random.default_rng(1000 + w)
+    n = 16
+    ties = np.array([0.0, 0.01, 0.02, 0.02, 0.05, 0.2])
+    with jax.enable_x64(x64):
+        dt = np.float64 if x64 else np.float32
+        for count in range(1, w + 1):
+            cursors = {count % w} if count < w else {0, 1, w // 2, w - 1}
+            for pos in sorted(cursors):
+                waits = np.where(rng.random((n, w)) < 0.5,
+                                 rng.choice(ties, (n, w)),
+                                 rng.uniform(0.0, 0.06, (n, w)))
+                steps = 0.2 + rng.choice(ties, (n, w))
+                steps[: n // 2] = 0.2                  # whole rows tied
+                early = waits + rng.uniform(0.0, 0.02, (n, w))
+                for buf in (waits, steps, early):      # outside the window
+                    buf[:, count:] = rng.uniform(-1.0, 10.0, (n, w - count))
+                delay = rng.uniform(0.0, 0.05, n)
+                args = [x.astype(dt) for x in (waits, steps, early, delay)]
+                args += [np.int32(pos), np.int32(count), np.int32(count + 2)]
+                got, want = network(*args), sorted_(*args)
+                for g, e in zip(got, want):
+                    assert _bits(g) == _bits(e), (count, pos)
+                bufs = np.stack(args[:2])
+                valid = np.arange(w) < count
+                assert _bits(net_med(bufs, valid, np.int32(count))) == \
+                    _bits(srt_med(bufs, valid, np.int32(count))), (count, pos)
+
+
+@needs_jax
+def test_paced_runner_has_no_sort_and_counts_the_network(monkeypatch):
+    """A paced job's compiled runner takes the window medians without a
+    device sort, and building it counts ``fabric.runner.median_network``
+    once for the job."""
+    import re
+
+    from repro.fabric import telemetry
+    from repro.fabric.backend import jnp_engine
+
+    scn = _scenario(paced=True, iters=24)
+    scn = dataclasses.replace(scn, jobs=scn.jobs[:1])
+    prep = jnp_engine._prep(scn)
+    data = {k: np.stack([v]) for k, v in prep.data.items()}
+    monkeypatch.setattr(jnp_engine, "_RUNNERS", {})
+    telemetry.disable()
+    telemetry.take()
+    telemetry.enable()
+    try:
+        fn = jnp_engine._get_runner(prep.sig, prep.static, KernelType.JNP,
+                                    scn.iters)
+        counters = telemetry.take().counters
+    finally:
+        telemetry.disable()
+        telemetry.take()
+    assert counters["fabric.runner.median_network"] == 1
+    hlo = fn.lower(data).as_text(dialect="hlo")
+    assert re.search(r"\bwhile\(", hlo)                # the lowered scan
+    assert not re.search(r"\bsort\(", hlo)
 
 
 # ---------------------------------------------------------------------------
