@@ -63,9 +63,6 @@ bench-smoke:     ## the CI benchmark smoke sections (ARTIFACTS= to persist)
 	$(PY) -m benchmarks.run --only batching $(if $(ARTIFACTS),--artifacts $(ARTIFACTS))
 	$(PY) -m benchmarks.run --only scenarios $(if $(ARTIFACTS),--artifacts $(ARTIFACTS))
 	$(PY) -m benchmarks.run --only topology $(if $(ARTIFACTS),--artifacts $(ARTIFACTS))
-	$(PY) -m benchmarks.run --only pacing
-	$(PY) -m benchmarks.run --only backend $(if $(ARTIFACTS),--artifacts $(ARTIFACTS))
-	$(PY) -m benchmarks.run --only kernels $(if $(ARTIFACTS),--artifacts $(ARTIFACTS))
 	$(PY) -m benchmarks.run --only advisor $(if $(ARTIFACTS),--artifacts $(ARTIFACTS))
 
 bench:           ## all benchmark sections

@@ -1,5 +1,8 @@
 """Benchmark aggregator: one section per paper artifact.
 
+Every section tabulates simulated outcomes; none times the host. Speed is
+measured on the chip by ``bench/run.py`` (``BENCHMARK.json``).
+
   table1      — paper Table 1 (baseline vs coordination, 5 node counts)
   scaling     — paper Fig. 1/5 (observed vs ideal curves + CVs)
   taxonomy    — paper Fig. 2 / §3.3 (failure-mode attribution)
@@ -11,17 +14,11 @@
   batching    — continuous-batching sweep: batch size vs p99/throughput
                 (single stream vs batch-join fleets at high arrival rate)
   scenarios   — scenario-library smoke: every named scenario end to end
-  topology    — ranks vs step cost across fat_tree/rail/multi-pod and
-                ecmp_static vs adaptive_spray (sparse-fabric scaling)
-  pacing      — vectorized PacingBank vs scalar controllers (before/after)
-  speedup     — compiled-schedule engine vs seed per-call loop wall-clock
-  backend     — batched jnp grid sweep vs sequential reference engine
-                (kernel-registry backend, targets >= 50x warm)
-  kernels     — kernel micro-benchmarks: substrate (attention/rmsnorm/
-                wkv6/mamba) + the fabric registry hot paths (reference
-                vs jnp vs pallas-interpret at the dense-sweep shape)
+  topology    — ranks vs links and mean step time across fat_tree/rail/
+                multi-pod and ecmp_static vs adaptive_spray
   trace       — bundled-trace validation: fit + replay error report
                 (mean/p99 gates) and the congestion calibration sweep
+  advisor     — bottleneck attribution + what-if recommendations
   roofline    — per-cell roofline terms from the dry-run artifacts
 
 Run everything: ``PYTHONPATH=src python -m benchmarks.run``
@@ -35,7 +32,6 @@ from __future__ import annotations
 import argparse
 import os
 import sys
-import time
 
 
 def main() -> None:
@@ -43,8 +39,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     choices=["table1", "scaling", "taxonomy", "multitenant",
                              "lifecycle", "wfq", "batching", "scenarios",
-                             "topology", "pacing", "speedup", "backend",
-                             "kernels", "trace", "advisor", "roofline"])
+                             "topology", "trace", "advisor", "roofline"])
     ap.add_argument("--artifacts", default=None, metavar="DIR",
                     help="write sections' CSV/JSON artifacts into DIR")
     args = ap.parse_args()
@@ -90,27 +85,9 @@ def main() -> None:
         artifact_writers.append(scenarios.write_artifacts)
     if args.only in (None, "topology"):
         from benchmarks import topology_bench
-        sections.append(("topology_bench (sparse fabrics: ranks vs step "
-                         "cost, ecmp vs spray)", topology_bench.rows))
+        sections.append(("topology_bench (sparse fabrics: ranks vs links, "
+                         "ecmp vs spray)", topology_bench.rows))
         artifact_writers.append(topology_bench.write_artifacts)
-    if args.only in (None, "pacing"):
-        from benchmarks import pacing_bench
-        sections.append(("pacing (vectorized bank vs scalar controllers)",
-                         pacing_bench.rows))
-    if args.only in (None, "speedup"):
-        from benchmarks import engine_speedup
-        sections.append(("engine_speedup (compiled schedules vs seed loop)",
-                         engine_speedup.rows))
-    if args.only in (None, "backend"):
-        from benchmarks import backend_bench
-        sections.append(("backend_bench (batched jnp sweep vs sequential "
-                         "reference)", backend_bench.rows))
-        artifact_writers.append(backend_bench.write_artifacts)
-    if args.only in (None, "kernels"):
-        from benchmarks import kernel_bench
-        sections.append(("kernel_bench (substrate + fabric registry)",
-                         kernel_bench.rows))
-        artifact_writers.append(kernel_bench.write_artifacts)
     if args.only in (None, "trace"):
         from benchmarks import trace_validation
         sections.append(("trace_validation (bundled-trace fit + replay "
@@ -131,11 +108,10 @@ def main() -> None:
     failures = 0
     for title, fn in sections:
         print(f"\n=== {title} ===")
-        t0 = time.time()
         try:
             for ln in fn():
                 print(ln)
-            print(f"--- done in {time.time() - t0:.1f}s")
+            print("--- done")
         except Exception as e:  # noqa: BLE001
             failures += 1
             print(f"--- FAILED: {type(e).__name__}: {e}")

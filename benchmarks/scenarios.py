@@ -1,7 +1,7 @@
 """Scenario-library smoke: run every named scenario end to end.
 
 One row per :mod:`repro.fabric.scenario.library` entry — backend, tenant
-count, wall-clock, and the headline per-tenant metric — so CI catches a
+count, and the headline per-tenant metric — so CI catches a
 library scenario that stopped validating, stopped running, or lost its
 failure-mode signal. All entries run at test scale (seconds each).
 
@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
 from typing import List
 
 from repro.fabric.scenario import Scenario, library
@@ -25,18 +24,16 @@ _ROWS: List[str] = []
 
 def rows() -> List[str]:
     # memoized: the printed table and write_artifacts() share one run of
-    # the library (wall_ms in the CSV is the run that was printed)
+    # the library
     if _ROWS:
         return _ROWS
-    lines = ["scenario,backend,tenants,wall_ms,headline"]
+    lines = ["scenario,backend,tenants,headline"]
     for name in library.names():
         scn = library.build(name)
         # the declarative form is part of the contract: every library
         # entry must survive its own JSON round trip
         assert Scenario.from_json(scn.to_json()).to_dict() == scn.to_dict()
-        t0 = time.time()
         res = scn.run()
-        wall_ms = (time.time() - t0) * 1e3
         diags = res.diagnostics()
         parts = []
         for tname, d in diags.items():
@@ -46,7 +43,7 @@ def rows() -> List[str]:
             else:
                 parts.append(f"{tname}: {d['mean_step_s'] * 1e3:.0f}ms/step"
                              f" cv={d['cv']:.3f}")
-        lines.append(f"{name},{res.kind},{len(diags)},{wall_ms:.0f},"
+        lines.append(f"{name},{res.kind},{len(diags)},"
                      + " | ".join(parts))
     _ROWS.extend(lines)
     return _ROWS

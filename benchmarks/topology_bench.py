@@ -1,12 +1,11 @@
-"""Topology-scale bench: ranks vs per-step cost across fabric kinds.
+"""Topology-scale table: ranks vs materialized links across fabric kinds.
 
-One row per (topology kind, rank count, routing policy) cell — build
-wall-clock, steady per-step engine wall-clock, materialized link count,
-and the tenant's mean step time — demonstrating that the sparse kinds
-(``rail_optimized``, ``multi_pod``) hold build/step cost proportional to
-the tenants' footprint while the dense ``fat_tree`` table grows with the
-fabric, and showing what ``adaptive_spray`` pays/buys over ``ecmp_static``
-on multi-pod fabrics with parallel global links.
+One row per (topology kind, rank count, routing policy) cell — the
+materialized link count and the tenant's simulated mean step time —
+showing that the sparse kinds (``rail_optimized``, ``multi_pod``)
+materialize links in proportion to the tenants' footprint while the dense
+``fat_tree`` table grows with the fabric, and what ``adaptive_spray``
+buys over ``ecmp_static`` on multi-pod fabrics with parallel global links.
 
 ``--artifacts DIR`` persists the table as ``topology.csv``.
 """
@@ -14,7 +13,6 @@ from __future__ import annotations
 
 import os
 import statistics
-import time
 from typing import List
 
 from repro.fabric.engine import JobSpec
@@ -51,7 +49,7 @@ _ROWS: List[str] = []
 def rows() -> List[str]:
     if _ROWS:
         return _ROWS
-    lines = ["kind,routing,ranks,links,build_ms,step_ms,mean_step_s"]
+    lines = ["kind,routing,ranks,links,mean_step_s"]
     for kind, routing, rank_counts in _GRID:
         for n_ranks in rank_counts:
             spec = _spec(kind, n_ranks)
@@ -76,17 +74,11 @@ def rows() -> List[str]:
                 jobs=jobs,
                 policies=Policies(routing=routing),
                 iters=_ITERS, warmup=_WARMUP)
-            t0 = time.time()
-            topo = scn.topology.build()
-            build_ms = (time.time() - t0) * 1e3
-            t0 = time.time()
             res = scn.run()
-            step_ms = (time.time() - t0) * 1e3 / _ITERS
             mean_step = statistics.fmean(res.series("a"))
             lines.append(
                 f"{kind},{routing},{spec.n_ranks},{len(res.topo.links)},"
-                f"{build_ms:.2f},{step_ms:.3f},{mean_step:.6f}")
-            del topo
+                f"{mean_step:.6f}")
     _ROWS.extend(lines)
     return _ROWS
 

@@ -16,7 +16,6 @@ report) and ``trace_calibration.csv`` (the per-cell sweep table from
 from __future__ import annotations
 
 import os
-import time
 from typing import List
 
 from repro.fabric.trace import (BUNDLED_TRACES, calibrate, fit_trace,
@@ -47,16 +46,14 @@ def rows() -> List[str]:
                       "p99_rel_err,correlation,gates")
     for name in BUNDLED_TRACES:
         tr = load_trace(_trace_path(name))
-        t0 = time.time()
         fit = fit_trace(tr)
-        fit_ms = (time.time() - t0) * 1e3
         val = validate_result(fit.scenario.run(backend="reference"), tr)
         ov = val.overall()
         ok = ov["mean_rel_err"] <= MEAN_GATE and ov["p99_rel_err"] <= P99_GATE
         u = fit.scenario.congestion.u_mean \
             if fit.scenario.congestion is not None else 0.0
         lines.append(
-            f"{name}: {len(tr.records)} records, fit {fit_ms:.0f}ms, "
+            f"{name}: {len(tr.records)} records, "
             f"u_mean={u:.3f}, mean_err={ov['mean_rel_err'] * 100:.2f}% "
             f"p99_err={ov['p99_rel_err'] * 100:.2f}% "
             f"[{'PASS' if ok else 'FAIL'} gates {MEAN_GATE:.0%}/"
@@ -80,13 +77,11 @@ def rows() -> List[str]:
             raise AssertionError(
                 f"{name}: replay error outside acceptance gates: {val!r}")
     for name in BUNDLED_TRACES:
-        t0 = time.time()
         cal = calibrate(_trace_path(name))
-        wall = time.time() - t0
         _CALIBRATIONS[name] = cal
         lines.append(
             f"calibrate {name}: backend={cal.backend} "
-            f"cells={len(cal.cells)} in {wall:.1f}s, score "
+            f"cells={len(cal.cells)}, score "
             f"{cal.seed_validation.score():.4f} -> "
             f"{cal.best_validation.score():.4f} at {cal.best_params} "
             f"({'improved' if cal.improved else 'seed cell optimal'})")

@@ -48,7 +48,7 @@ ARCH_IDS: List[str] = list(ARCH_MODULES)
 
 # Archs with a sub-quadratic decode path (SSM state / rolling SWA window /
 # context-parallel hybrid): these run long_500k. Pure full-attention archs
-# skip it (DESIGN.md §4).
+# skip it.
 LONG_CONTEXT_ARCHS = {"rwkv6-3b", "jamba-v0.1-52b", "mixtral-8x7b"}
 
 
@@ -64,7 +64,7 @@ def get_optimized_config(arch: str) -> ModelConfig:
 
     The paper-faithful baseline keeps published head counts (replicated attention
     compute when heads % 16 != 0); the optimized variant pads heads to the next
-    multiple of 16 so attention TP shards cleanly. See EXPERIMENTS.md §Perf.
+    multiple of 16 so attention TP shards cleanly.
     """
     cfg = get_model_config(arch)
     return cfg.replace(pad_heads_to=16)

@@ -3,7 +3,7 @@
 24L encoder + 24L decoder, d_model=1024, 16H (kv=16), d_ff=8192, vocab=256206.
 The audio frontend is a STUB per assignment: input_specs() provides precomputed
 frame embeddings (B, S_enc, d_model); the transformer backbone is real.
-Shape budget: S_enc = S_dec = seq_len/2 (see DESIGN.md §4).
+Shape budget: S_enc = S_dec = seq_len/2.
 """
 from repro.configs.base import ModelConfig
 

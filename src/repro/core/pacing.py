@@ -172,7 +172,7 @@ class PacingBank:
     ``_median`` formula, and the delay update replicates the scalar branch
     structure with masks. This is what lets the engine keep its bit-equality
     contract with the per-rank reference loop while dropping the per-rank
-    Python overhead (``benchmarks.run --only pacing``).
+    Python overhead.
     """
 
     def __init__(self, cfg: PacingConfig, n_ranks: int):

@@ -5,13 +5,9 @@ with compiled collective schedules and tightened stochastic-model kernels,
 all of which are required to be *bit-identical* in arithmetic. This module
 preserves the original implementation — per-call :func:`all_reduce` inside
 the iteration loop, the original ``random.gauss``-based samplers, eager
-:class:`IterationRecord` construction — so that
-
-  * tests can assert ``simulate(cfg).step_times ==
-    simulate_reference(cfg).step_times`` exactly (same RNG streams, same
-    float operations), and
-  * the engine-speedup benchmark measures against the true seed wall-clock
-    rather than a partially optimized strawman.
+:class:`IterationRecord` construction — so that tests can assert
+``simulate(cfg).step_times == simulate_reference(cfg).step_times`` exactly
+(same RNG streams, same float operations).
 
 Do not "fix" or optimize this module; it is the comparison point.
 """

@@ -5,7 +5,7 @@ The reference engine steps one scenario at a time in Python; a dense
 interpreter once per variant per iteration. This module compiles the
 engine's iteration loop into a single ``lax.scan`` and ``vmap``s it over
 scenario variants, so a 256-point sweep executes as one XLA program
-(``benchmarks.run --only backend`` measures the speedup).
+(``bench/run.py`` measures its speed on the chip).
 
 The key structural fact that makes this possible: **every random stream
 the engine consumes is feedback-free.** Compute samples

@@ -59,8 +59,8 @@ class SimConfig:
 
         Free parameters (straggler mix, congestion coupling) were fit by
         coordinate search against the paper's 20 published numbers (5 node
-        counts x {throughput, CV} x {baseline, coordination}); see
-        EXPERIMENTS.md §Table-1 for the resulting comparison.
+        counts x {throughput, CV} x {baseline, coordination});
+        ``python -m benchmarks.run --only table1`` prints the comparison.
         """
         pacing = PacingConfig(
             enabled=True, window=6, cv_threshold=0.05, skew_threshold=0.04,

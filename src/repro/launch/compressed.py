@@ -7,7 +7,7 @@ exchanges pod-partial gradients explicitly over the pod axis as int8 with
 per-block scales via a ppermute ring (``repro.optim.compress``), cutting
 cross-pod wire bytes ~3.9x vs bf16 all-reduce.
 
-Trade-offs (measured in EXPERIMENTS.md §Perf):
+Trade-offs:
   * optimizer moments are pod-replicated here (zero1 off) to keep the
     manual-pod in_specs simple — the target term is collective, not memory;
   * the lowered variant quantizes without error feedback (EF changes

@@ -14,7 +14,7 @@ single-pod 16x16 mesh and the 2x16x16 multi-pod mesh, then record:
   * sharding fallbacks             — dims that replicated (divisibility).
 
 Results land in ``results/dryrun/<arch>__<shape>__<mesh>[__variant].json`` and
-feed EXPERIMENTS.md §Dry-run and §Roofline.
+feed the roofline section of ``benchmarks.run``.
 
 NOTE: the two lines above MUST run before any other import — jax locks the
 device count on first init. Do not set this flag globally: smoke tests and
@@ -90,7 +90,7 @@ def _cost_of(model, opt_cfg, mesh, shape, mb: int = 1,
     # Single-trip attention scan so cost_analysis sees the full SDPA work
     # (it counts while-loop bodies once). SSM recurrence inner scans stay
     # chunked: their FLOPs are ~1% of a layer (projections dominate), so
-    # the residual undercount is immaterial — see DESIGN.md.
+    # the residual undercount is immaterial.
     prev = os.environ.get("REPRO_ATTN_BLOCK_K")
     prev_cm = os.environ.get("REPRO_COST_MODE")
     os.environ["REPRO_ATTN_BLOCK_K"] = str(max(shape.seq_len, 512))

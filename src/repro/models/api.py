@@ -90,7 +90,7 @@ def train_input_specs(cfg: ModelConfig, shape: ShapeConfig
     """Inputs for ``loss_fn``: tokens (B, S+1) plus modality extras."""
     B, S = shape.global_batch, shape.seq_len
     if cfg.is_encoder_decoder:
-        # budget: S_enc = S_dec = S/2 (DESIGN.md §4)
+        # budget: S_enc = S_dec = S/2
         Se = Sd = S // 2
         return {
             "tokens": _sds((B, Sd + 1)),

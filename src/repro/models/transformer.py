@@ -535,7 +535,7 @@ def _xent_chunked(p: Params, cfg: ModelConfig, hidden_normed: jax.Array,
                   labels: jax.Array, valid: jax.Array) -> jax.Array:
     """Memory-lean loss: project + cross-entropy one sequence chunk at a
     time, so peak logits memory is (B, chunk, V) instead of (B, S, V).
-    Beyond-paper memory-term optimization (EXPERIMENTS.md §Perf)."""
+    Beyond-paper memory-term optimization."""
     B, S, D = hidden_normed.shape
     C = min(cfg.loss_chunk, S)
     n = S // C

@@ -22,9 +22,8 @@ declared tiers — on CPU they run in interpret mode, so this file
 exercises the identical kernel code CI ships to TPU), and the
 ``Scenario``/``ScenarioGrid``/``Policies.backend`` selection surfaces.
 Runs in tier-1; the heavier grid sweeps carry the slow marker (CI's
-backend-equivalence job also runs ``benchmarks.run --only backend`` for
-the 50x target, and the pallas-interpret job runs the ``-k pallas``
-subset under ``JAX_PLATFORMS=cpu``).
+pallas-interpret job runs the ``-k pallas`` subset under
+``JAX_PLATFORMS=cpu``).
 """
 import dataclasses
 import random
